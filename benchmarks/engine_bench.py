@@ -23,8 +23,7 @@ overhead, not real scale-out — the column exists to track that overhead
 and to exercise the meshed program end-to-end in CI.  All repro imports
 live inside functions so ``--shard`` can set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before jax
-initializes (run_shard re-execs itself in a subprocess when the current
-process already locked a smaller device count).
+initializes (run_shard always runs it in a CPU-only child process).
 """
 from __future__ import annotations
 
@@ -246,17 +245,17 @@ def _shard_rows(quick: bool = True) -> list[dict]:
 
 
 def run_shard(quick: bool = True) -> list[dict]:
-    """Sharded-vs-single column; re-execs in a subprocess with 8 forced CPU
-    host devices when this process already locked a smaller device count
-    (XLA_FLAGS only takes effect before jax initializes)."""
-    import jax
-    if jax.device_count() >= int(np.prod(SHARD_MESH)):
-        return _shard_rows(quick)
+    """Sharded-vs-single column on 8 forced CPU host devices, always in a
+    child process: this process does not touch jax before spawning it, and
+    the child is held to the CPU (``JAX_PLATFORMS=cpu``), so it never needs
+    a device this process may hold (XLA_FLAGS only takes effect before jax
+    initializes)."""
     repo = pathlib.Path(__file__).resolve().parents[1]
     env = dict(os.environ)
     flags = env.get("XLA_FLAGS", "")
     if "host_platform_device_count" not in flags:
         env["XLA_FLAGS"] = (flags + " " + _FORCE_FLAG).strip()
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (str(repo / "src"), env.get("PYTHONPATH", "")) if p)
     cmd = [sys.executable, "-m", "benchmarks.engine_bench", "--shard"]

@@ -236,6 +236,7 @@ def run(quick: bool = True) -> list[dict]:
     record = {
         "bench": "kernels",
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "backend_mode": mode,
         "quick": quick,
         "rows": rows,

@@ -9,29 +9,24 @@ platform ridge point (AI* = peak_flops / peak_bw).
 
 This replaced the dormant LM dry-run table: the repo's hot kernels are the
 FedGS graph/solver/aggregator Pallas kernels, so the roofline now tracks
-the records that ``perf_assert.py`` gates on.  The boundness classification
-comes from the MODEL (AI vs ridge), so it is meaningful even for interpret
-rows; the achieved-fraction columns are only meaningful for compiled rows
-(interpret wall-clock measures the Pallas interpreter, not the kernel).
+the records that ``perf_assert.py`` gates on.  The boundness
+classification comes from the MODEL (AI vs ridge); the achieved fractions
+come from the record's wall-clock, so only a compiled chip record gets a
+roofline (interpret wall-clock measures the Pallas interpreter).
 
-Platform ceilings (nominal, order-of-magnitude anchors):
-
-  cpu   ~50 GFLOP/s f32, ~20 GB/s   (single-core container envelope)
-  tpu   ~197 TFLOP/s bf16/f32-accum, ~1.2 TB/s HBM  (TPU v5p-class)
+Peaks come from the one table keyed by ``device_kind``
+(``repro/utils/peaks.py``, with its source); a chip kind that is not in
+the table raises.  A record taken on the CPU is not a chip measurement and
+gets no roofline.
 """
 from __future__ import annotations
 
 import json
 import pathlib
 
-BENCH = pathlib.Path(__file__).resolve().parent / "results" / "BENCH_kernels.json"
+from repro.utils.peaks import chip_peaks
 
-# (peak GFLOP/s, peak GB/s) per jax.default_backend()
-PEAKS = {
-    "cpu": (50.0, 20.0),
-    "tpu": (197000.0, 1200.0),
-    "gpu": (60000.0, 2000.0),
-}
+BENCH = pathlib.Path(__file__).resolve().parent / "results" / "BENCH_kernels.json"
 
 
 def load() -> dict | None:
@@ -42,9 +37,11 @@ def load() -> dict | None:
 
 def run(quick: bool = True) -> list[dict]:
     rec = load()
-    if rec is None:
+    if rec is None or rec.get("backend") == "cpu":
         return []
-    peak_f, peak_b = PEAKS.get(rec.get("backend", "cpu"), PEAKS["cpu"])
+    peaks = chip_peaks(rec["device_kind"])
+    peak_f = peaks["flops_bf16"] / 1e9           # GFLOP/s
+    peak_b = peaks["hbm_bytes_s"] / 1e9          # GB/s
     ridge = peak_f / peak_b
     out = []
     for r in rec["rows"]:
@@ -64,8 +61,8 @@ def run(quick: bool = True) -> list[dict]:
 def summarize(rows) -> list[str]:
     if not rows:
         return ["", "== Roofline (kernel records) ==",
-                "  no results/BENCH_kernels.json — run the 'kernels' "
-                "section first"]
+                "  no chip record in results/BENCH_kernels.json — run the "
+                "'kernels' section on a TPU first"]
     mode = rows[0]["backend_mode"]
     ridge = rows[0]["ridge_ai"]
     out = ["", f"== Roofline over BENCH_kernels.json (ridge AI* = {ridge}; "

@@ -31,9 +31,9 @@ SECTIONS = ["table2", "fig4", "table3", "table4", "dynamic", "scaling",
 
 def _section(name: str, quick: bool):
     if name == "shard":
-        # sharded-vs-single run_batch: run_shard re-execs itself with 8
-        # forced CPU host devices when this process has fewer (XLA_FLAGS
-        # only takes effect before jax initializes)
+        # sharded-vs-single run_batch: run_shard runs it in a CPU-only
+        # child with 8 forced host devices (XLA_FLAGS only takes effect
+        # before jax initializes)
         from benchmarks import engine_bench as m
         rows = m.run_shard(quick=quick)
         return rows, m.summarize_shard(rows)
@@ -89,6 +89,9 @@ def main(argv=None):
         ap.error("--quick and --full are mutually exclusive")
     quick = not args.full
     sections = args.only.split(",") if args.only else SECTIONS
+    # the runtime section spawns compile children that need the device:
+    # run it while this process has not touched one yet
+    sections = sorted(sections, key=lambda s: s != "runtime")
 
     RESULTS.mkdir(parents=True, exist_ok=True)
     all_rows = []
@@ -119,4 +122,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.fed.runtime import enable_compile_cache
+    enable_compile_cache()
     main()

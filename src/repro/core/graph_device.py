@@ -64,11 +64,14 @@ class GraphConfig:
 # ------------------------------------------------------------------- stages
 def dot_sim(u: jax.Array, *, backend: str = "ref",
             interpret: bool | None = None) -> jax.Array:
-    """V = U Uᵀ.  The pallas backend runs the tiled MXU matmul."""
+    """V = U Uᵀ.  The pallas backend runs the tiled MXU matmul.  The ref
+    matmul asks for float32 precision: a TPU's default f32 matmul rounds
+    its operands to bf16, and V feeds exp(-V/σ²), which magnifies that
+    error far past what the pallas kernel's float32 products show."""
     if backend == "pallas":
         from repro.kernels.ops import pairwise_similarity
         return pairwise_similarity(u, interpret=interpret)
-    return u @ u.T
+    return jnp.matmul(u, u.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def cosine_sim(u: jax.Array, *, clamp: bool = True, backend: str = "ref",

@@ -196,9 +196,12 @@ def init_agg_state(params0, n_clients: int,
 def memory_scatter_reduce_ref(mem, upd, sel, valid, w):
     """The memory family's REF backend, shared by the switch branch, the
     benchmark and the parity tests (so 'ref vs pallas' always compares the
-    shipped path): O(mP) masked row scatter + one (N,)·(N, P) tensordot."""
+    shipped path): O(mP) masked row scatter + one (N,)·(N, P) tensordot,
+    at float32 precision (a TPU's default f32 contraction rounds operands
+    to bf16)."""
     mem2 = mem.at[sel].set(jnp.where(valid[:, None], upd, mem[sel]))
-    return mem2, jnp.tensordot(w, mem2, axes=(0, 0))
+    return mem2, jnp.tensordot(w, mem2, axes=(0, 0),
+                               precision=jax.lax.Precision.HIGHEST)
 
 
 # ----------------------------------------------------- robust combine rules
@@ -244,10 +247,12 @@ def trimmed_mean_combine(updf, valid, beta):
 def krum_pairwise_ref(updf):
     """REF backend of the Krum squared-distance panel, shared by the switch
     branch, the bench and the ref-vs-pallas parity tests:
-    ``D = ||x_i||² + ||x_j||² − 2 X Xᵀ``."""
+    ``D = ||x_i||² + ||x_j||² − 2 X Xᵀ``, the cross term at float32
+    precision (a TPU's default f32 matmul rounds operands to bf16)."""
     x = updf.astype(jnp.float32)
     n2 = jnp.sum(x * x, axis=1)
-    return n2[:, None] + n2[None, :] - 2.0 * (x @ x.T)
+    xx = jnp.matmul(x, x.T, precision=jax.lax.Precision.HIGHEST)
+    return n2[:, None] + n2[None, :] - 2.0 * xx
 
 
 def krum_select(updf, valid, f_byz, multi, *, backend: str = "ref",
@@ -428,8 +433,10 @@ def make_aggregator_step(n: int, m: int, params_like, *, data_sizes=None,
             hit = valid & (lsel >= 0) & (lsel < rows)
             mem = state["mem"].at[jnp.where(hit, lsel, rows)].set(updf)
             wn_l = jax.lax.dynamic_slice_in_dim(wn, off, rows)
-            red = jax.lax.psum(jnp.tensordot(wn_l, mem, axes=(0, 0)),
-                               panel_axis)
+            red = jax.lax.psum(
+                jnp.tensordot(wn_l, mem, axes=(0, 0),
+                              precision=jax.lax.Precision.HIGHEST),
+                panel_axis)
         elif backend == "pallas":
             from repro.kernels.ops import memory_aggregate
             mem, red = memory_aggregate(state["mem"], updf, sel, valid, wn,

@@ -28,7 +28,7 @@ from repro.fed.client import make_local_trainer, make_loss_prober
 from repro.fed.faults_device import HostFaultInjector, make_fault_process
 from repro.fed.models import FedModel
 from repro.fed.runtime import (
-    AsyncCheckpointWriter, ProgramCache, enable_compile_cache,
+    AsyncCheckpointWriter, ProgramCache,
 )
 from repro.fed.server import ServerAggregator
 from repro.fed.telemetry import NULL_TRACER, runtime_snapshot
@@ -50,9 +50,6 @@ class FLConfig:
     # K rounds (0 = static graph; paper §3.2 "dynamically built and polished
     # round by round")
     graph_refresh_every: int = 0
-    # persistent XLA compile cache (DESIGN.md §15): a re-launched run pays
-    # compile once per (program, topology); None = in-process cache only
-    compile_cache_dir: Optional[str] = None
 
 
 @dataclass
@@ -124,8 +121,6 @@ class FLEngine:
             "eval", lambda: jax.jit(lambda p, x, y: (
                 model.loss(p, x, y), model.accuracy(p, x, y))))
         self.counts = np.zeros(self.n)
-        if cfg.compile_cache_dir is not None:
-            enable_compile_cache(cfg.compile_cache_dir)
 
     def runtime_stats(self) -> dict:
         """The shared telemetry snapshot (same shape as

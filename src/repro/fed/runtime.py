@@ -6,14 +6,17 @@ around the compiled programs hot-path too.  Four pieces, shared by both
 engines (``fed/scan_engine.py``, ``fed/engine.py``) and the service
 front-end (``launch/serve.py``):
 
-``enable_compile_cache(dir)``
+``enable_compile_cache()``
     Wires ``jax``'s persistent compilation cache
     (``jax.experimental.compilation_cache``) so a re-launched sweep or a
     second service process pays XLA compile once per (program, device
     topology) — entries are keyed by XLA on the optimized HLO + compile
     options + backend, so heterogeneous programs never collide.  Thresholds
     are dropped to cache-everything: the engine's programs are few and
-    re-compiled from scratch they dominate warm-start latency.
+    re-compiled from scratch they dominate warm-start latency.  The one way
+    to place the cache is ``JAX_COMPILATION_CACHE_DIR``; unset, it lives at
+    the fixed ``<repo>/.jax_cache``.  Only the command-line entry points
+    turn it on — never an import or an engine constructor.
 
 ``ProgramCache``
     A bounded LRU over the engines' jitted programs (the old ``_jits``
@@ -49,6 +52,7 @@ import threading
 import time
 import warnings
 from collections import OrderedDict
+from pathlib import Path
 from typing import Callable, Optional
 
 import jax
@@ -61,14 +65,26 @@ warnings.filterwarnings(
 
 
 # ------------------------------------------------------- persistent cache
-def enable_compile_cache(cache_dir: str | os.PathLike) -> str:
-    """Point jax's persistent compilation cache at ``cache_dir`` (created
-    if missing) and drop the size/time thresholds so every engine program
-    is cached.  Idempotent; returns the directory.  Keying (trust the
-    cache): XLA fingerprints the optimized HLO module + compile options +
-    backend/topology, so a program compiled for one device count never
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def compile_cache_dir() -> str:
+    """The persistent compile cache's directory: ``JAX_COMPILATION_CACHE_DIR``
+    when set, else the fixed ``<repo>/.jax_cache``.  The path is part of
+    the cache key, so it is never derived from a temporary name, a process
+    id or the time."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(REPO_ROOT / ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Point jax's persistent compilation cache at ``compile_cache_dir()``
+    (created if missing) and drop the size/time thresholds so every engine
+    program is cached.  Idempotent; returns the directory.  Keying (trust
+    the cache): XLA fingerprints the optimized HLO module + compile options
+    + backend/topology, so a program compiled for one device count never
     serves another."""
-    cache_dir = os.fspath(cache_dir)
+    cache_dir = compile_cache_dir()
     os.makedirs(cache_dir, exist_ok=True)
     if jax.config.jax_compilation_cache_dir != cache_dir:
         # the persistent-cache layer initializes ONCE per process, at the
@@ -79,7 +95,7 @@ def enable_compile_cache(cache_dir: str | os.PathLike) -> str:
             compilation_cache as _cc,
         )
         _cc.reset_cache()
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir

@@ -68,7 +68,7 @@ Dynamic 3DG
 
 Mesh scale-out (DESIGN.md §13)
   ``ScanConfig.mesh=(cells,)`` or ``(cells, silo)`` runs ``run_batch``
-  under ``jax.experimental.shard_map`` on ``launch.mesh.make_engine_mesh``:
+  under ``jax.shard_map`` on ``launch.mesh.make_engine_mesh``:
   sweep cells shard over the "cells" axis (embarrassingly parallel —
   per-cell subsystem state stays device-local; uneven batches are padded by
   repeating the last cell and the pad trajectories dropped), and the "silo"
@@ -113,12 +113,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.ckpt import load_checkpoint, save_checkpoint
 from repro.fed.runtime import (
-    AsyncCheckpointWriter, CarryHandle, ProgramCache, enable_compile_cache,
+    AsyncCheckpointWriter, CarryHandle, ProgramCache,
 )
 from repro.fed.telemetry import (
     NULL_TRACER, fault_corruption_norm, round_telemetry, runtime_snapshot,
@@ -157,6 +156,8 @@ AGGREGATORS = AGG_FAMILIES     # ("fedavg", "fedavgm", "fedadam",
 FAULTS = FAULT_FAMILIES        # ("none", "sign_flip", "gaussian_noise",
                                #  "scaled", "straggler_stale")
 SILO_REDUCES = ("gather", "psum")
+# clients per block of the dynamic 3DG's all-clients init probe round
+INIT_BLOCK = 512
 
 
 @dataclass(frozen=True)
@@ -202,11 +203,9 @@ class ScanConfig:
     # runtime layer (DESIGN.md §15): donate the scan carry into each
     # segment program (in-place HBM reuse; use-after-donation raises via
     # CarryHandle), overlap device compute with host traj fetch + async
-    # checkpoint writes, persist XLA compiles across processes, and bound
-    # the in-process program cache
+    # checkpoint writes, and bound the in-process program cache
     donate_carry: bool = True
     async_pipeline: bool = True
-    compile_cache_dir: Optional[str] = None
     program_cache_size: int = 32
     # in-scan telemetry channel (DESIGN.md §17): opt-in per-round stage
     # health metrics (update norms / NaN fraction / clip rate, sampler
@@ -457,6 +456,27 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
         if with_memory else 0
     chunk = -(-m // silo)              # per-silo local-training clients
 
+    def init_embeddings(ikey):
+        """Embeddings after one all-clients probe round from a fresh model
+        (the paper's everyone-available-at-init assumption), as in
+        FLEngine.install_dynamic_graph.  Past ``INIT_BLOCK`` clients the
+        round trains and embeds one block of clients at a time: a single
+        vmap over every client holds all N clients' activations at once
+        (N=4096 small-CNN clients x 4 cells asks a 16 GB v5e for 23 GB)."""
+        params = model.init(ikey)
+        keys = jax.random.split(ikey, n)
+        lr0 = jnp.float32(cfg.lr)
+        if n <= INIT_BLOCK:
+            return embed_mean(trainer(params, xs, ys, sizes_i, lr0, keys))
+
+        def block(ix):
+            return embed_mean(trainer(params, xs[ix], ys[ix], sizes_i[ix],
+                                      lr0, keys[ix]))
+        nb = -(-n // INIT_BLOCK)
+        ix = jnp.minimum(jnp.arange(nb * INIT_BLOCK), n - 1)
+        emb = jax.lax.map(block, ix.reshape(nb, INIT_BLOCK))
+        return emb.reshape(nb * INIT_BLOCK, -1)[:n]
+
     def init(cell):
         """The FULL scan carry — everything a bitwise-exact resume needs
         (plus the round index and rng cell keys, which live in the cell /
@@ -465,13 +485,7 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
         params0 = model.init(key0)
 
         if dynamic:
-            # init: one all-clients probe round from a fresh model (the
-            # paper's everyone-available-at-init assumption), as in
-            # FLEngine.install_dynamic_graph
-            ikey = cell["init_key"]
-            stacked = trainer(model.init(ikey), xs, ys, sizes_i,
-                              jnp.float32(cfg.lr), jax.random.split(ikey, n))
-            emb0 = embed_mean(stacked)
+            emb0 = init_embeddings(cell["init_key"])
             h0 = rebuild_h(emb0)
         else:
             emb0 = jnp.zeros((1, 1), jnp.float32)
@@ -652,8 +666,6 @@ class ScanEngine:
         self.sink = sink
         self._tel_parts: list = []    # [(t0, k, telemetry_host)] per run
         self._writer_stats: Optional[dict] = None
-        if cfg.compile_cache_dir is not None:
-            enable_compile_cache(cfg.compile_cache_dir)
 
     def runtime_stats(self) -> dict:
         """The unified telemetry snapshot (DESIGN.md §17): program-cache
@@ -734,8 +746,8 @@ class ScanEngine:
                 fn = jax.vmap(fn)
             if mesh is not None:
                 spec = engine_batch_spec(self.cfg.cell_sharding)
-                fn = shard_map(fn, mesh=mesh, in_specs=(spec,),
-                               out_specs=spec, check_rep=False)
+                fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec,),
+                                   out_specs=spec, check_vma=False)
             return jax.jit(fn)
         return self._programs.get((flags, batched, silo, panel), build)
 
@@ -763,8 +775,8 @@ class ScanEngine:
             if mesh is not None:
                 cspecs = self._carry_specs(stacked, flags, silo, panel, fn)
                 spec = engine_batch_spec(self.cfg.cell_sharding)
-                fn = shard_map(fn, mesh=mesh, in_specs=(spec,),
-                               out_specs=cspecs, check_rep=False)
+                fn = jax.shard_map(fn, mesh=mesh, in_specs=(spec,),
+                                   out_specs=cspecs, check_vma=False)
             return jax.jit(fn)
         return self._programs.get((flags, "init", silo, panel), build)
 
@@ -780,8 +792,9 @@ class ScanEngine:
                 cspecs = self._carry_specs(stacked, flags, silo, panel,
                                            jax.vmap(cl["init"]))
                 spec = engine_batch_spec(self.cfg.cell_sharding)
-                fn = shard_map(fn, mesh=mesh, in_specs=(spec, cspecs, P()),
-                               out_specs=(cspecs, spec), check_rep=False)
+                fn = jax.shard_map(fn, mesh=mesh,
+                                   in_specs=(spec, cspecs, P()),
+                                   out_specs=(cspecs, spec), check_vma=False)
             # donate the carry (arg 1): the (params, moments, (N, P) memory
             # panel, chain + sampler state) buffers are reused in place
             # across segments instead of fresh HBM allocations per segment;

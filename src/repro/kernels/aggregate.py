@@ -38,9 +38,10 @@ Invalid/pad scatter slots are encoded as ``sel = -1`` (never equal to a
 row id); pad rows of the panel carry zero weight, pad columns are sliced
 off by the ``kernels/ops.py`` wrapper.  Tiles are f32; the (1, Tp)
 accumulator and (1, Tm) sel row are sub-tile but legal (the compiler pads
-sublanes).  Worst-case VMEM at the (512, 2048) panel tile with Tm = 256
-update chunks: mem + newmem 8 MiB + upd 2 MiB + one-hot 0.5 MiB ≈ 10.5
-MiB, under the 16 MiB/core budget.  On CPU the kernel runs under
+sublanes).  VMEM at the (512, 2048) panel tile with Tm = 256 update
+chunks: mem + new_mem double-buffered 16 MiB + upd 4 MiB + one-hot and
+scatter temporaries — over the compiler's 16 MiB scoped default, so the
+call raises its limit to ``AGG_VMEM_LIMIT``.  On CPU the kernel runs under
 ``interpret=True`` — tiles scale up at large panels to keep the grid
 small (every interpret grid step re-writes the (N, P) output; see the
 perf note in ``kernels/ops.py``).
@@ -52,10 +53,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 AGG_TN = 256        # memory-panel tile rows (clients)
 AGG_TP = 512        # memory-panel tile cols (flat params)
 AGG_TM = 256        # sampled-update chunk rows
+# scoped-VMEM limit for the kernel: the (512, 2048) heuristic tile
+# double-buffers mem and new_mem (16 MiB) beside the update chunk, the
+# one-hot and the scatter temporaries — over the compiler's 16 MiB
+# default, well inside a v5e core's 128 MiB of VMEM
+AGG_VMEM_LIMIT = 48 * 2 ** 20
 
 
 def _memagg_kernel(sel_ref, w_ref, upd_ref, mem_ref, newmem_ref, red_ref):
@@ -76,14 +83,20 @@ def _memagg_kernel(sel_ref, w_ref, upd_ref, mem_ref, newmem_ref, red_ref):
     # second one-hot dot on the non-finite mask (DESIGN.md §12: finite
     # panels are bit-identical; a client's non-finite entries land as NaN
     # in that client's row only, as a NaN-poisoned row marks itself).
-    rows = jax.lax.broadcasted_iota(jnp.float32, (tn, tm), 0) + i * tn
+    # int32 iota converted to f32: Mosaic's iota is integer-only
+    rows = (jax.lax.broadcasted_iota(jnp.int32, (tn, tm), 0)
+            + i * tn).astype(jnp.float32)
     onehot = (rows == sel_ref[...]).astype(jnp.float32)
     u = upd_ref[...]
     finite = jnp.isfinite(u)
+    # every dot asks for float32 precision: at a lower one the MXU would
+    # round the scattered updates to bf16 and the scatter stops being exact
     scat = jnp.dot(onehot, jnp.where(finite, u, 0.0),
-                   preferred_element_type=jnp.float32)
+                   preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
     bad = jnp.dot(onehot, 1.0 - finite.astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
+                  preferred_element_type=jnp.float32,
+                  precision=jax.lax.Precision.HIGHEST)
     scat = jnp.where(bad > 0.0, jnp.float32(jnp.nan), scat)
     hit = jnp.sum(onehot, axis=1, keepdims=True) > 0.5
     newmem_ref[...] = jnp.where(hit, scat, newmem_ref[...])
@@ -95,7 +108,8 @@ def _memagg_kernel(sel_ref, w_ref, upd_ref, mem_ref, newmem_ref, red_ref):
     @pl.when(k == nk - 1)                          # tile fully scattered
     def _reduce():
         red_ref[...] += jnp.dot(w_ref[...], newmem_ref[...],
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit,
@@ -124,5 +138,6 @@ def memagg_pallas(mem: jax.Array, upd: jax.Array, sel: jax.Array,
                    pl.BlockSpec((1, tile_p), lambda j, i, k: (0, j))],
         out_shape=[jax.ShapeDtypeStruct((n, p), jnp.float32),
                    jax.ShapeDtypeStruct((1, p), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=AGG_VMEM_LIMIT),
         interpret=interpret,
     )(sel, w, upd, mem)

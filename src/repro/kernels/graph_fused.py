@@ -42,25 +42,30 @@ FUSED_TILE = 128
 def _fused_kernel(n, clamp, u_ref, ut_ref, scal_ref, out_ref, stat_ref):
     phase, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     t = out_ref.shape[0]
+    # float32 precision, like the ref matmul: V feeds exp(-V/σ²)
     v = jax.lax.dot_general(u_ref[...], ut_ref[...], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
     if clamp:
         v = jnp.maximum(v, 0.0)
     rows = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0) + i * t
     cols = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1) + j * t
     valid = (rows < n) & (cols < n)
 
+    # the (1, 2) [lo, hi] accumulator is updated as a vector: Mosaic
+    # cannot store scalars to VMEM
+    is_lo = jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1) == 0
+
     @pl.when((phase == 0) & (i == 0) & (j == 0))
     def _init():
-        stat_ref[0, 0] = jnp.inf
-        stat_ref[0, 1] = -jnp.inf
+        stat_ref[...] = jnp.where(is_lo, jnp.inf, -jnp.inf)
 
     @pl.when(phase == 0)
     def _stats():
-        stat_ref[0, 0] = jnp.minimum(stat_ref[0, 0],
-                                     jnp.min(jnp.where(valid, v, jnp.inf)))
-        stat_ref[0, 1] = jnp.maximum(stat_ref[0, 1],
-                                     jnp.max(jnp.where(valid, v, -jnp.inf)))
+        cur = stat_ref[...]
+        stat_ref[...] = jnp.where(
+            is_lo, jnp.minimum(cur, jnp.min(jnp.where(valid, v, jnp.inf))),
+            jnp.maximum(cur, jnp.max(jnp.where(valid, v, -jnp.inf))))
         out_ref[...] = jnp.zeros_like(out_ref)
 
     @pl.when(phase == 1)

@@ -44,7 +44,8 @@ def _krum_kernel(x_ref, xt_ref, out_ref):
     rj = jnp.sum(xtj * xtj, axis=0, keepdims=True)    # (1, T) partial ||x_j||²
     out_ref[...] += (ri + rj) - 2.0 * jax.lax.dot_general(
         xi, xtj, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)        # as the ref cross term
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_k", "interpret"))

@@ -26,7 +26,8 @@ def _sim_kernel(u_ref, ut_ref, out_ref):
 
     out_ref[...] += jax.lax.dot_general(
         u_ref[...], ut_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)        # as the ref matmul
 
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "tile_k", "interpret"))

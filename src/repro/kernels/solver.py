@@ -35,7 +35,8 @@ reconstructed exactly where they are consumed:
 
 All tiles are f32; min tile (8, 128) per the TPU tiling constraints — the
 (1, T) argmax rows and (1, 1) accumulator outputs are sub-tile but legal
-(the compiler pads sublanes).  The running-reduction outputs use a constant
+(the compiler pads sublanes).  The accumulators are read and written as
+(1, 1) vectors, never as scalars: Mosaic cannot store a scalar to VMEM.  The running-reduction outputs use a constant
 ``index_map`` so the accumulator tile stays resident across the sequential
 grid (the same revisiting-accumulator pattern as ``pairwise_similarity``).
 On CPU the kernels run under ``interpret=True`` (see ``kernels/ops.py``).
@@ -89,16 +90,19 @@ def _masked_argmax_kernel(diag_ref, r_ref, mask_ref, val_ref, idx_ref):
 
     @pl.when(b == 0)
     def _init():
-        val_ref[0, 0] = -jnp.inf
-        idx_ref[0, 0] = 0
+        val_ref[...] = jnp.full((1, 1), -jnp.inf, jnp.float32)
+        idx_ref[...] = jnp.zeros((1, 1), jnp.int32)
 
     mx = jnp.max(gain)
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
     pos = jnp.min(jnp.where(gain == mx, cols, t))     # first max in block
-    # strict > + left-to-right grid order == jnp.argmax first-max tie-break
-    better = mx > val_ref[0, 0]
-    idx_ref[0, 0] = jnp.where(better, b * t + pos, idx_ref[0, 0])
-    val_ref[0, 0] = jnp.where(better, mx, val_ref[0, 0])
+    # strict > + left-to-right grid order == jnp.argmax first-max tie-break;
+    # the accumulators are updated as (1, 1) vectors: Mosaic cannot store
+    # scalars to VMEM
+    cur_v, cur_i = val_ref[...], idx_ref[...]
+    better = mx > cur_v
+    idx_ref[...] = jnp.where(better, b * t + pos, cur_i)
+    val_ref[...] = jnp.where(better, mx, cur_v)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -136,18 +140,18 @@ def _best_swap_update(delta, bi, bj, np_cols, val_ref, flat_ref):
 
     @pl.when((bi == 0) & (bj == 0))
     def _init():
-        val_ref[0, 0] = -jnp.inf
-        flat_ref[0, 0] = 0
+        val_ref[...] = jnp.full((1, 1), -jnp.inf, jnp.float32)
+        flat_ref[...] = jnp.zeros((1, 1), jnp.int32)
 
     mx = jnp.max(delta)
     rows = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 1)
     flat = (rows + bi * tm) * np_cols + (cols + bj * tn)
     pos = jnp.min(jnp.where(delta == mx, flat, jnp.int32(2 ** 31 - 1)))
-    cur_v, cur_f = val_ref[0, 0], flat_ref[0, 0]
+    cur_v, cur_f = val_ref[...], flat_ref[...]        # (1, 1) vectors
     better = (mx > cur_v) | ((mx == cur_v) & (pos < cur_f))
-    flat_ref[0, 0] = jnp.where(better, pos, cur_f)
-    val_ref[0, 0] = jnp.where(better, mx, cur_v)
+    flat_ref[...] = jnp.where(better, pos, cur_f)
+    val_ref[...] = jnp.where(better, mx, cur_v)
 
 
 def _swap_gain_kernel(a_ref, b_ref, q_ref, val_ref, flat_ref):

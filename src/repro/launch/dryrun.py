@@ -9,10 +9,14 @@ Usage:
 
 Results are cached incrementally in benchmarks/results/dryrun/*.json.
 """
-# The next two lines MUST run before any other import — jax locks the device
+# The next lines MUST run before any other import — jax locks the device
 # count on first init, and the production mesh needs 512 host devices.
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS",
+                                                                ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512").strip()
 
 import argparse
 import json
@@ -33,13 +37,17 @@ from repro.launch.steps import (make_train_step, make_prefill_step,
 from repro.launch.variants import apply_variant, VARIANTS
 from repro.sharding.ctx import use_sharding
 from repro.utils.hlo import analyze as hlo_analyze
+from repro.utils.peaks import chip_peaks
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / "benchmarks" / "results" / "dryrun"
 
-# TPU v5e roofline constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s/link
+# roofline constants of the chip the production mesh models (per chip),
+# from the one peaks table
+TARGET_CHIP = "TPU v5 lite"
+_PEAKS = chip_peaks(TARGET_CHIP)
+PEAK_FLOPS = _PEAKS["flops_bf16"]
+HBM_BW = _PEAKS["hbm_bytes_s"]
+ICI_BW = _PEAKS["ici_link_bytes_s"]
 
 
 def _mem_dict(compiled):

@@ -17,7 +17,11 @@ Floyd–Warshall + the QUBO solve for N clients), lowered as one jit program.
 Results: benchmarks/results/dryrun/fedsim__*.json (same record schema).
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS",
+                                                                ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512").strip()
 
 import argparse
 import json

@@ -11,11 +11,12 @@ Federated-simulation path — a long-lived ``SimService`` over ONE hot
 availability scenarios / aggregators) batch into a single ``run_batch``
 program, and per-round metrics stream back segment by segment through the
 engine's donated/pipelined ``run_batch_stream`` (DESIGN.md §15) instead of
-arriving post-scan.  With ``--compile-cache-dir`` a restarted service
-re-loads its XLA programs from the persistent cache:
+arriving post-scan.  Run from the command line, a restarted service
+re-loads its XLA programs from the persistent compile cache, which lives in
+``$JAX_COMPILATION_CACHE_DIR`` (default ``<repo>/.jax_cache``):
 
   PYTHONPATH=src python -m repro.launch.serve --fedsim --cells 4 \
-      --rounds 24 --segment 8 --compile-cache-dir /tmp/jaxcache
+      --rounds 24 --segment 8
 """
 from __future__ import annotations
 
@@ -56,7 +57,8 @@ class SimService:
 
     The service owns a single ``ScanEngine``: its ``ProgramCache`` keeps the
     compiled programs hot across ``drain()`` calls (same static shapes =
-    zero recompiles), and ``ScanConfig.compile_cache_dir`` persists them
+    zero recompiles), and the persistent compile cache the command-line
+    entry point turns on (``fed.runtime.enable_compile_cache``) keeps them
     across service restarts.  ``submit`` accepts everything
     ``ScanEngine.cell`` does — the ``lax.switch`` subsystems mean arbitrary
     sampler/availability/aggregator mixes still compile to one program.
@@ -208,7 +210,6 @@ def _fedsim_main(args):
                         seed=args.seed)
     cfg = ScanConfig(rounds=args.rounds, m=4, local_steps=2, batch_size=8,
                      eval_every=1, sampler="uniform",
-                     compile_cache_dir=args.compile_cache_dir,
                      telemetry=bool(getattr(args, "telemetry", False)))
     tracer, sink = make_observability(args)
     svc = SimService(ScanEngine(ds, logistic_regression(), cfg,
@@ -266,8 +267,6 @@ def main(argv=None):
     ap.add_argument("--segment", type=int, default=8,
                     help="streaming segment length (0 = one segment)")
     ap.add_argument("--n-clients", type=int, default=16)
-    ap.add_argument("--compile-cache-dir", default=None,
-                    help="persistent XLA compile cache directory")
     ap.add_argument("--telemetry", action="store_true",
                     help="enable the in-scan per-round health channel "
                          "(ScanConfig.telemetry)")
@@ -340,4 +339,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.fed.runtime import enable_compile_cache
+    enable_compile_cache()
     main()

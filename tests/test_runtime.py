@@ -12,8 +12,8 @@ Pinned claims:
   bounds the program set (the old ``_jits`` dict grew unboundedly);
 * the ``AsyncCheckpointWriter`` preserves submission order and re-raises
   worker errors instead of dropping them;
-* ``ScanConfig.compile_cache_dir`` populates a persistent XLA cache and
-  changes no results;
+* the persistent XLA cache, placed through ``JAX_COMPILATION_CACHE_DIR``
+  (default ``<repo>/.jax_cache``), populates and changes no results;
 * ``run_batch_stream`` yields segments incrementally, and the
   ``SimService`` front-end streams per-request updates that reassemble to
   the exact ``run_batch`` histories;
@@ -32,7 +32,8 @@ from repro.core.availability_device import make_process
 from repro.fed.aggregator_device import make_aggregator_process
 from repro.fed.models import logistic_regression
 from repro.fed.runtime import (
-    AsyncCheckpointWriter, CarryHandle, ProgramCache,
+    AsyncCheckpointWriter, CarryHandle, ProgramCache, compile_cache_dir,
+    enable_compile_cache,
 )
 from repro.fed.scan_engine import ScanConfig, ScanEngine
 
@@ -248,20 +249,42 @@ def test_ckpt_every_without_path_segments(ds16):
                                       getattr(fused[0], f), err_msg=f)
 
 
-def test_compile_cache_dir_populates_and_preserves_results(ds16, tmp_path):
+def test_compile_cache_dir_populates_and_preserves_results(ds16, tmp_path,
+                                                           monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache as cc
     ds = ds16
     rounds = 4
     cache = str(tmp_path / "xla-cache")
     plain = ScanEngine(ds, logistic_regression(), _cfg(rounds))
-    cached = ScanEngine(ds, logistic_regression(),
-                        _cfg(rounds, compile_cache_dir=cache))
     a = plain.run_batch(_cells(plain, ds, rounds, "fedavg", "GE"))
-    b = cached.run_batch(_cells(cached, ds, rounds, "fedavg", "GE"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    try:
+        assert enable_compile_cache() == cache
+        cached = ScanEngine(ds, logistic_regression(), _cfg(rounds))
+        b = cached.run_batch(_cells(cached, ds, rounds, "fedavg", "GE"))
+    finally:
+        # later tests in this process must not write into tmp_path
+        cc.reset_cache()
+        for k, v in saved.items():
+            jax.config.update(k, v)
     for f in HIST_FIELDS:
         np.testing.assert_array_equal(getattr(a[0], f), getattr(b[0], f),
                                       err_msg=f)
     assert os.path.isdir(cache) and os.listdir(cache), \
         "persistent compile cache left empty"
+
+
+def test_compile_cache_dir_defaults_to_repo(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR unset the cache resolves to the fixed
+    ``<repo>/.jax_cache`` (resolving creates nothing)."""
+    import pathlib
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache_dir() == str(repo / ".jax_cache")
 
 
 # --------------------------------------------------------------- SimService

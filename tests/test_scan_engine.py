@@ -136,6 +136,24 @@ def test_dynamic_3dg_refresh_in_scan(synthetic_ds):
     assert sh.val_loss[-1] < sh.val_loss[0]
 
 
+def test_dynamic_3dg_blocked_init_round(synthetic_ds, monkeypatch):
+    """Past INIT_BLOCK clients the all-clients init probe round trains and
+    embeds in blocks (lax.map), ragged last block included; it gives the
+    single-vmap round's trajectory."""
+    import repro.fed.scan_engine as se
+    ds = synthetic_ds
+    hists = []
+    for block in (se.INIT_BLOCK, 8):
+        monkeypatch.setattr(se, "INIT_BLOCK", block)
+        eng = ScanEngine(ds, logistic_regression(),
+                         _scan_cfg(6, 6, sampler="fedgs",
+                                   graph_refresh_every=3))
+        hists.append(eng.run(eng.cell(seed=0, mode=_mode("LN", ds))))
+    np.testing.assert_array_equal(hists[0].sel, hists[1].sel)
+    np.testing.assert_allclose(hists[0].val_loss, hists[1].val_loss,
+                               atol=1e-6)
+
+
 def test_poc_selection_invariants(synthetic_ds):
     """In-scan Power-of-Choice: S_t subset of A_t, |S_t| = min(M, |A_t|),
     counts track the selections (the host-loop fallback is gone)."""
