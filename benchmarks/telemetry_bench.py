@@ -23,16 +23,20 @@ sink throughput
     above engine round rates.
 
 Artifacts for eyeballing land in ``results/telemetry/``: the run's
-``metrics.jsonl`` and the host-span ``trace.json`` (chrome://tracing).
+``metrics.jsonl`` and one ``jax.profiler`` trace under ``trace/`` (host
+spans and device ops on one clock; its ``perfetto_trace.json.gz`` loads
+in ui.perfetto.dev).
 
   PYTHONPATH=src python -m benchmarks.telemetry_bench
 """
 from __future__ import annotations
 
+import argparse
 import hashlib
 import json
 import os
 import pathlib
+import shutil
 import time
 
 import numpy as np
@@ -129,8 +133,10 @@ def run(quick: bool = True) -> list[dict]:
     import jax
 
     from benchmarks.common import pallas_backend_mode
-    from repro.fed.telemetry import Tracer
-    from repro.obs import JSONLMetricsSink, read_metrics_jsonl
+    from repro.launch.obs_cli import (
+        finish_observability, make_observability,
+    )
+    from repro.obs import read_metrics_jsonl
 
     rounds = 40 if quick else 120
     seg = 8
@@ -160,19 +166,25 @@ def run(quick: bool = True) -> list[dict]:
     print(f"[telemetry_bench] sink: {ev_s:,.0f} events/s, {mb_s:.1f} MB/s",
           flush=True)
 
-    # ------------- artifacts: metrics.jsonl + trace.json ------------------
+    # ------------- artifacts: metrics.jsonl + profiler trace -------------
+    # the launch CLIs' --trace-dir / --metrics-jsonl path: one jax.profiler
+    # trace holds the engine's host spans and the device's ops
     art = RESULTS / "telemetry"
     art.mkdir(parents=True, exist_ok=True)
     mpath = art / "metrics.jsonl"
     if mpath.exists():
         mpath.unlink()
-    tracer = Tracer()
-    with JSONLMetricsSink(str(mpath), run="telemetry_bench") as sink:
+    shutil.rmtree(art / "trace", ignore_errors=True)
+    obs = argparse.Namespace(trace_dir=str(art / "trace"),
+                             metrics_jsonl=str(mpath))
+    tracer, sink = make_observability(obs, run="telemetry_bench")
+    try:
         eng_art, cells_art = _mk(rounds, telemetry=True)
         eng_art.tracer, eng_art.sink = tracer, sink
         eng_art.run_batch(cells_art, ckpt_every=seg)
+    finally:
+        finish_observability(sink, obs)
     n_round_events = len(read_metrics_jsonl(str(mpath), kind="round"))
-    tracer.export_chrome(str(art / "trace.json"))
     spans = {k: v["count"] for k, v in tracer.summary().items()}
 
     row = {
@@ -206,12 +218,11 @@ def summarize(rows) -> list[str]:
                    f"events/s ({r['jsonl_mb_per_s']:.1f} MB/s JSONL)")
         out.append(f"  artifacts  : {r['round_events_streamed']} round "
                    f"events -> results/telemetry/metrics.jsonl, spans "
-                   f"{r['spans']} -> results/telemetry/trace.json")
+                   f"{r['spans']} -> results/telemetry/trace/")
     return out
 
 
 if __name__ == "__main__":
-    import argparse
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--full", action="store_true")
     a = ap.parse_args()

@@ -327,6 +327,16 @@ class ScanHistory:
 
 
 # ---------------------------------------------------------------- the program
+# The round step's stages, in order: each is one ``jax.named_scope`` in
+# ``step`` (none nested in another), so every instruction of the compiled
+# program carries its stage in its ``op_name`` metadata and a device trace
+# can be split by stage.  These names are the program's contract with the
+# trace readers; the init program runs under one scope, "init".
+ROUND_STAGES = ("availability", "sampler", "local_train", "fault",
+                "aggregate", "graph_refresh", "eval", "fairness",
+                "telemetry")
+
+
 def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
                     use_masks: bool, with_memory: bool = False, *,
                     with_fault: bool = False, with_stale: bool = False,
@@ -481,78 +491,86 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
         """The FULL scan carry — everything a bitwise-exact resume needs
         (plus the round index and rng cell keys, which live in the cell /
         checkpoint metadata)."""
-        key0 = cell["key"]
-        params0 = model.init(key0)
+        with jax.named_scope("init"):
+            key0 = cell["key"]
+            params0 = model.init(key0)
 
-        if dynamic:
-            emb0 = init_embeddings(cell["init_key"])
-            h0 = rebuild_h(emb0)
-        else:
-            emb0 = jnp.zeros((1, 1), jnp.float32)
-            h0 = cell["h"]
-        astate0 = init_agg_state(params0, n, memory_rows=mem_rows,
-                                 tau_rows=n if with_memory else 0)
-        carry0 = {"agg": astate0,
-                  "counts": jnp.zeros((n,), jnp.float32),
-                  "h": h0, "emb": emb0,
-                  "proc": cell.get("proc_state", {}),
-                  "sampler": cell.get("sampler_state", {})}
-        if with_fault:
-            # latency chain from the cell's eager init; the (rows, P)
-            # stale-update panel is sized here because P is model-dependent
-            carry0["fault"] = init_fault_state(
-                cell["fault_state"], params0, n if with_stale else 0)
+            if dynamic:
+                emb0 = init_embeddings(cell["init_key"])
+                h0 = rebuild_h(emb0)
+            else:
+                emb0 = jnp.zeros((1, 1), jnp.float32)
+                h0 = cell["h"]
+            astate0 = init_agg_state(params0, n, memory_rows=mem_rows,
+                                     tau_rows=n if with_memory else 0)
+            carry0 = {"agg": astate0,
+                      "counts": jnp.zeros((n,), jnp.float32),
+                      "h": h0, "emb": emb0,
+                      "proc": cell.get("proc_state", {}),
+                      "sampler": cell.get("sampler_state", {})}
+            if with_fault:
+                # latency chain from the cell's eager init; the (rows, P)
+                # stale-update panel is sized here because P is
+                # model-dependent
+                carry0["fault"] = init_fault_state(
+                    cell["fault_state"], params0, n if with_stale else 0)
         return carry0
 
     def step(cell, carry, t):
         astate, counts = carry["agg"], carry["counts"]
         h, emb = carry["h"], carry["emb"]
         pstate, sstate = carry["proc"], carry["sampler"]
-        params = astate["prev"]        # the aggregator state IS the
-        lr = lrs[t]                    # global-params carry
-        key = jax.random.fold_in(cell["key"], t)
+        # the aggregator state IS the global-params carry
+        params = astate["prev"]
 
         # 1. availability A_t — the shared device-native process draw
         # (core/availability_device.proc_draw: family step -> Bernoulli
         # -> force-one); the process state rides the scan carry
-        if use_masks:
-            avail = cell["masks"][t]
-        else:
-            avail, pstate = proc_draw(
-                cell["proc"], pstate,
-                jax.random.fold_in(cell["avail_key"], t), t)
+        with jax.named_scope("availability"):
+            if use_masks:
+                avail = cell["masks"][t]
+            else:
+                avail, pstate = proc_draw(
+                    cell["proc"], pstate,
+                    jax.random.fold_in(cell["avail_key"], t), t)
 
         # 2. sampler: S_t subset of A_t, |S_t| = min(M, |A_t|) — the
         # switch step dispatches on the cell's family; the sampler
         # state rides the scan carry like the availability state
-        skey = jax.random.fold_in(cell["sampler_key"], t)
-        s, sstate = sampler_step(
-            cell["sampler"], sstate, skey,
-            {"h": h, "counts": counts, "params": params}, avail, t)
-        sel, valid = select(s)
+        with jax.named_scope("sampler"):
+            skey = jax.random.fold_in(cell["sampler_key"], t)
+            s, sstate = sampler_step(
+                cell["sampler"], sstate, skey,
+                {"h": h, "counts": counts, "params": params}, avail, t)
+            sel, valid = select(s)
+            sel_out = sel.astype(jnp.int32)
 
         # 3. vmap'd local training on the M gathered clients — under a
         # silo'd mesh each shard trains its ceil(M/s) chunk (same
         # per-client keys) and all_gathers the stacked updates
-        key, sub = jax.random.split(key)
-        keys_m = jax.random.split(sub, m)
-        if silo > 1:
-            pad = chunk * silo - m
-            sel_p = jnp.concatenate([sel, sel[-1:].repeat(pad, 0)]) \
-                if pad else sel
-            keys_p = jnp.concatenate([keys_m, keys_m[-1:].repeat(pad, 0)]) \
-                if pad else keys_m
-            i0 = jax.lax.axis_index(ENGINE_SILO_AXIS) * chunk
-            sel_l = jax.lax.dynamic_slice_in_dim(sel_p, i0, chunk)
-            keys_l = jax.lax.dynamic_slice_in_dim(keys_p, i0, chunk)
-            local_l = trainer(params, xs[sel_l], ys[sel_l], sizes_i[sel_l],
-                              lr, keys_l)
-            local = jax.tree_util.tree_map(
-                lambda a: jax.lax.all_gather(
-                    a, ENGINE_SILO_AXIS, axis=0, tiled=True)[:m], local_l)
-        else:
-            local = trainer(params, xs[sel], ys[sel], sizes_i[sel], lr,
-                            keys_m)
+        with jax.named_scope("local_train"):
+            lr = lrs[t]
+            key = jax.random.fold_in(cell["key"], t)
+            key, sub = jax.random.split(key)
+            keys_m = jax.random.split(sub, m)
+            if silo > 1:
+                pad = chunk * silo - m
+                sel_p = jnp.concatenate([sel, sel[-1:].repeat(pad, 0)]) \
+                    if pad else sel
+                keys_p = jnp.concatenate(
+                    [keys_m, keys_m[-1:].repeat(pad, 0)]) if pad else keys_m
+                i0 = jax.lax.axis_index(ENGINE_SILO_AXIS) * chunk
+                sel_l = jax.lax.dynamic_slice_in_dim(sel_p, i0, chunk)
+                keys_l = jax.lax.dynamic_slice_in_dim(keys_p, i0, chunk)
+                local_l = trainer(params, xs[sel_l], ys[sel_l],
+                                  sizes_i[sel_l], lr, keys_l)
+                local = jax.tree_util.tree_map(
+                    lambda a: jax.lax.all_gather(
+                        a, ENGINE_SILO_AXIS, axis=0, tiled=True)[:m],
+                    local_l)
+            else:
+                local = trainer(params, xs[sel], ys[sel], sizes_i[sel], lr,
+                                keys_m)
 
         # 3b. fault injection — the per-cell fault switch corrupts the
         # byz slots of the flat (M, P) update panel BETWEEN training and
@@ -560,67 +578,74 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
         # replays); benign cells pass through the identity branch
         fault_mag = None
         if with_fault:
-            fstate = carry["fault"]
-            cleanf = jax.vmap(fravel)(local)
-            updf, fstate = fault_step(
-                cell["fault"], fstate,
-                jax.random.fold_in(cell["fault_key"], t),
-                cleanf, fravel(params), avail, t, sel,
-                valid)
-            local = jax.vmap(funravel)(updf)
-            if with_telemetry:
-                # corruption magnitude at the seam, where the clean flat
-                # panel is still in scope (DESIGN.md §17)
-                fault_mag = fault_corruption_norm(updf, cleanf, valid)
+            with jax.named_scope("fault"):
+                fstate = carry["fault"]
+                cleanf = jax.vmap(fravel)(local)
+                updf, fstate = fault_step(
+                    cell["fault"], fstate,
+                    jax.random.fold_in(cell["fault_key"], t),
+                    cleanf, fravel(params), avail, t, sel,
+                    valid)
+                local = jax.vmap(funravel)(updf)
+                if with_telemetry:
+                    # corruption magnitude at the seam, where the clean
+                    # flat panel is still in scope (DESIGN.md §17)
+                    fault_mag = fault_corruption_norm(updf, cleanf, valid)
 
         # 4. server update — the aggregator switch step dispatches on
         # the cell's family (Eq. 18 weights: pads carry zero weight;
-        # the fedavg branch is bit-identical to the legacy aggregate())
+        # the fedavg branch is bit-identical to the legacy aggregate()),
+        # then 5. the count update v^{t+1}
         prev_params = params
-        agg_w = sizes_f[sel] * valid
-        params, astate = agg_step(
-            cell["agg"], astate, jax.random.fold_in(cell["agg_key"], t),
-            local, agg_w, s, avail, t, sel, valid)
-
-        # 5. count update v^{t+1}
-        counts = counts + s.astype(jnp.float32)
+        with jax.named_scope("aggregate"):
+            agg_w = sizes_f[sel] * valid
+            params, astate = agg_step(
+                cell["agg"], astate, jax.random.fold_in(cell["agg_key"], t),
+                local, agg_w, s, avail, t, sel, valid)
+            counts = counts + s.astype(jnp.float32)
 
         # dynamic 3DG: refresh participants' embeddings; rebuild every K
         if dynamic:
-            e_sel = embed_mean(local)
-            emb = emb.at[sel].set(
-                jnp.where(valid[:, None], e_sel, emb[sel]))
-            h = jax.lax.cond(
-                (t + 1) % cfg.graph_refresh_every == 0,
-                rebuild_h, lambda e: h, emb)
+            with jax.named_scope("graph_refresh"):
+                e_sel = embed_mean(local)
+                emb = emb.at[sel].set(
+                    jnp.where(valid[:, None], e_sel, emb[sel]))
+                h = jax.lax.cond(
+                    (t + 1) % cfg.graph_refresh_every == 0,
+                    rebuild_h, lambda e: h, emb)
 
         # 6. eval (cond-gated to the eval_every cadence)
         def do_eval(_):
             return model.loss(params, xv, yv), model.accuracy(params, xv, yv)
 
-        if cfg.eval_every == 1:
-            vl, va = do_eval(None)
-        else:
-            vl, va = jax.lax.cond(
-                (jnp.mod(t, cfg.eval_every) == 0) | (t == cfg.rounds - 1),
-                do_eval,
-                lambda _: (jnp.float32(jnp.nan), jnp.float32(jnp.nan)),
-                None)
+        with jax.named_scope("eval"):
+            if cfg.eval_every == 1:
+                vl, va = do_eval(None)
+            else:
+                vl, va = jax.lax.cond(
+                    (jnp.mod(t, cfg.eval_every) == 0)
+                    | (t == cfg.rounds - 1),
+                    do_eval,
+                    lambda _: (jnp.float32(jnp.nan), jnp.float32(jnp.nan)),
+                    None)
         # fairness metrics — the shared device twins (core/fairness.py)
-        cvar = count_variance_device(counts)
-        gini = gini_device(counts)
+        with jax.named_scope("fairness"):
+            cvar = count_variance_device(counts)
+            gini = gini_device(counts)
         out = {"val_loss": vl, "val_acc": va, "count_var": cvar,
-               "gini": gini, "sel": sel.astype(jnp.int32), "valid": valid}
+               "gini": gini, "sel": sel_out, "valid": valid}
         if with_telemetry:
             # the in-scan health channel (DESIGN.md §17): pure reductions
             # over this step's intermediates — consumers only, nothing
             # feeds back into the carry or the history fields above
-            out["telemetry"] = round_telemetry(
-                avail=avail, valid=valid, sel=sel, local=local,
-                params_prev=prev_params, params_new=params, weights=agg_w,
-                h=h, clip_thresh=cfg.telemetry_clip_thresh,
-                tau=astate["tau"] if with_memory else None, t=t,
-                fault_mag=fault_mag)
+            with jax.named_scope("telemetry"):
+                out["telemetry"] = round_telemetry(
+                    avail=avail, valid=valid, sel=sel, local=local,
+                    params_prev=prev_params, params_new=params,
+                    weights=agg_w, h=h,
+                    clip_thresh=cfg.telemetry_clip_thresh,
+                    tau=astate["tau"] if with_memory else None, t=t,
+                    fault_mag=fault_mag)
         carry1 = {"agg": astate, "counts": counts, "h": h, "emb": emb,
                   "proc": pstate, "sampler": sstate}
         if with_fault:

@@ -1,5 +1,5 @@
 """Unified telemetry layer (DESIGN.md §17): in-scan stage health metrics,
-host-side span tracing with profiler hooks, and the shared
+host-side spans on the profiler's clock, and the shared
 ``runtime_stats`` snapshot.
 
 The engine runs a whole FedGS round — 3DG rebuild, availability draw,
@@ -26,16 +26,17 @@ in-scan health channel (``round_telemetry``)
     (assumption log #24).
 
 host-side span tracer (``Tracer``)
-    Zero-dependency nested spans around the host runtime — build /
-    lower / compile / dispatch / device_get / checkpoint-write — each
-    span also entering ``jax.named_scope`` so the operations traced
-    under it carry the span name into HLO and (with ``--profile``)
-    ``jax.profiler`` XLA traces line up with the host spans.  Exports
-    Chrome/Perfetto ``trace.json``.  Span durations are HOST wall-clock
-    around ASYNC dispatch (assumption log #25): a "dispatch" span times
-    enqueue, not device compute — device time comes from the profiler
-    hook, and compile time from the ``ProgramCache`` executable-cache
-    probe (DESIGN.md §15).
+    Spans around the host runtime — program get / dispatch /
+    device_get / metrics emit / checkpoint write — each entered as a
+    ``jax.profiler.TraceAnnotation``: with ``--trace-dir`` they land in
+    the one ``jax.profiler`` trace beside the device's ops, on its
+    clock, and the tracer keeps bounded per-name aggregates.  Span
+    durations are HOST wall-clock around ASYNC dispatch (assumption log
+    #25): a "dispatch" span times enqueue, not device compute — device
+    time per round stage comes from the trace (each stage of the round
+    step is a ``jax.named_scope``, ``scan_engine.ROUND_STAGES``), and
+    compile time from the ``ProgramCache`` executable-cache probe
+    (DESIGN.md §15).
 
 ``runtime_snapshot``
     One merged counters snapshot shared by ``ScanEngine``, ``FLEngine``
@@ -46,8 +47,6 @@ host-side span tracer (``Tracer``)
 """
 from __future__ import annotations
 
-import json
-import os
 import threading
 import time
 from contextlib import contextmanager
@@ -185,137 +184,64 @@ def fault_corruption_norm(updf, cleanf, valid):
 
 # ------------------------------------------------------- host span tracer
 class Tracer:
-    """Zero-dependency nested span tracer with Chrome-trace export and
-    ``jax`` profiler hooks.
+    """Host spans on the profiler's clock, with per-name aggregates.
 
-    ``span(name)`` is a context manager: it enters ``jax.named_scope``
-    (so device ops traced inside carry the span name into HLO / XLA
-    profiles) and, when the tracer is enabled, records a Chrome
-    complete-event with host wall-clock start/duration, thread id and
-    nesting depth.  Thread-safe — checkpoint-writer spans record from
-    the writer thread and show up on their own trace row.
+    ``span(name, **attrs)`` is a context manager.  On an enabled tracer it
+    enters ``jax.profiler.TraceAnnotation(name, **attrs)``, so that while
+    a ``jax.profiler`` trace is recording (``--trace-dir``) the span lands
+    in the same trace as the device's ops, on the same clock, and it adds
+    the span's host wall-clock duration to the per-name aggregates
+    (count / total_ms / max_ms) that ``summary()`` returns: memory stays
+    bounded by the number of distinct names, however long the process
+    runs.  Thread-safe: checkpoint-writer spans record from the writer's
+    thread.  A disabled tracer does nothing; the engines default to the
+    shared ``NULL_TRACER``.
 
-    ``profile_dir`` arms the ``jax.profiler.trace`` hook:
-    ``start_profiler()`` / ``stop_profiler()`` bracket a run so the XLA
-    device trace lands next to the host spans' ``trace.json``.
+    Span durations are HOST wall-clock around ASYNC dispatch (assumption
+    log #25): a "dispatch" span times enqueue, not device compute.
+    Device time per round stage comes from the ``jax.named_scope`` of each
+    stage inside the compiled program (``scan_engine.ROUND_STAGES``)."""
 
-    A disabled tracer (``enabled=False``) still enters
-    ``jax.named_scope`` but records nothing — the engines default to a
-    shared module-level ``NULL_TRACER``."""
-
-    def __init__(self, *, enabled: bool = True,
-                 profile_dir: Optional[str] = None):
+    def __init__(self, *, enabled: bool = True):
         self.enabled = enabled
-        self.profile_dir = profile_dir
-        self._events: list[dict] = []
+        self._stats: dict[str, dict] = {}
         self._lock = threading.Lock()
-        self._local = threading.local()
-        self._epoch = time.perf_counter()
-        self._profiling = False
-
-    # ------------------------------------------------------------ spans
-    def _depth(self) -> int:
-        return getattr(self._local, "depth", 0)
 
     @contextmanager
     def span(self, name: str, **attrs):
-        with jax.named_scope(name):
-            if not self.enabled:
+        if not self.enabled:
+            yield self
+            return
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name, **attrs):
                 yield self
-                return
-            self._local.depth = self._depth() + 1
-            t0 = time.perf_counter()
-            try:
-                yield self
-            finally:
-                dur = time.perf_counter() - t0
-                self._local.depth -= 1
-                ev = {"name": name,
-                      "ts": (t0 - self._epoch) * 1e6,       # us
-                      "dur": dur * 1e6,
-                      "tid": threading.get_ident(),
-                      "depth": self._local.depth}
-                if attrs:
-                    ev["args"] = {k: (v if isinstance(v, (int, float, str,
-                                                          bool, type(None)))
-                                      else repr(v))
-                                  for k, v in attrs.items()}
-                with self._lock:
-                    self._events.append(ev)
-
-    # --------------------------------------------------------- profiler
-    def start_profiler(self):
-        """Arm ``jax.profiler.trace`` (XLA device trace) into
-        ``profile_dir`` — no-op without a directory."""
-        if self.profile_dir and not self._profiling:
-            os.makedirs(self.profile_dir, exist_ok=True)
-            jax.profiler.start_trace(self.profile_dir)
-            self._profiling = True
-
-    def stop_profiler(self):
-        if self._profiling:
-            jax.profiler.stop_trace()
-            self._profiling = False
-
-    # ----------------------------------------------------------- export
-    def events(self) -> list[dict]:
-        with self._lock:
-            return list(self._events)
-
-    def clear(self):
-        with self._lock:
-            self._events.clear()
+        finally:
+            ms = (time.perf_counter() - t0) * 1e3
+            with self._lock:
+                s = self._stats.setdefault(
+                    name, {"count": 0, "total_ms": 0.0, "max_ms": 0.0})
+                s["count"] += 1
+                s["total_ms"] += ms
+                s["max_ms"] = max(s["max_ms"], ms)
 
     def summary(self) -> dict:
         """Per-span-name aggregates: count / total_ms / max_ms."""
-        out: dict[str, dict] = {}
-        for ev in self.events():
-            s = out.setdefault(ev["name"],
-                               {"count": 0, "total_ms": 0.0, "max_ms": 0.0})
-            ms = ev["dur"] / 1e3
-            s["count"] += 1
-            s["total_ms"] += ms
-            s["max_ms"] = max(s["max_ms"], ms)
-        for s in out.values():
-            s["total_ms"] = round(s["total_ms"], 3)
-            s["max_ms"] = round(s["max_ms"], 3)
-        return out
-
-    def export_chrome(self, path: str) -> str:
-        """Write the recorded spans as a Chrome/Perfetto-loadable
-        ``trace.json`` (complete "X" events, microsecond timestamps) and
-        return the path.  Load via chrome://tracing or ui.perfetto.dev;
-        with the profiler hook armed, the XLA trace written into
-        ``profile_dir`` covers the same wall-clock window."""
-        pid = os.getpid()
-        evs = [{"name": ev["name"], "ph": "X", "pid": pid,
-                "tid": ev["tid"], "ts": round(ev["ts"], 3),
-                "dur": round(ev["dur"], 3),
-                "args": ev.get("args", {"depth": ev["depth"]})}
-               for ev in self.events()]
-        doc = {"traceEvents": evs, "displayTimeUnit": "ms",
-               "otherData": {"schema": TELEMETRY_SCHEMA_VERSION,
-                             "tool": "repro.fed.telemetry.Tracer"}}
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        with open(path, "w") as f:
-            json.dump(doc, f)
-        return path
+        with self._lock:
+            return {k: {"count": s["count"],
+                        "total_ms": round(s["total_ms"], 3),
+                        "max_ms": round(s["max_ms"], 3)}
+                    for k, s in self._stats.items()}
 
 
 NULL_TRACER = Tracer(enabled=False)
 
 
-def make_tracer(trace_dir: Optional[str] = None,
-                profile: bool = False) -> Tracer:
-    """CLI-knob constructor: ``--trace-dir`` enables span recording (the
-    chrome export lands there), ``--profile`` additionally arms the
-    ``jax.profiler`` hook into ``<trace_dir>/xla``."""
-    if not trace_dir and not profile:
-        return NULL_TRACER
-    pdir = os.path.join(trace_dir or ".", "xla") if profile else None
-    return Tracer(enabled=True, profile_dir=pdir)
+def make_tracer(trace_dir: Optional[str] = None) -> Tracer:
+    """CLI-knob constructor: ``--trace-dir`` enables the host spans (the
+    ``jax.profiler`` trace they land in is recorded by
+    ``launch.obs_cli``); without it, the ``NULL_TRACER``."""
+    return Tracer() if trace_dir else NULL_TRACER
 
 
 # ------------------------------------------------------ unified snapshot

@@ -129,6 +129,7 @@ def memagg_pallas(mem: jax.Array, upd: jax.Array, sel: jax.Array,
     grid = (p // tile_p, n // tile_n, mm // tile_m)   # chunks innermost
     return pl.pallas_call(
         _memagg_kernel,
+        name="memagg_pallas",
         grid=grid,
         in_specs=[pl.BlockSpec((1, tile_m), lambda j, i, k: (0, k)),
                   pl.BlockSpec((1, tile_n), lambda j, i, k: (0, i)),

@@ -124,6 +124,7 @@ def floyd_warshall_pallas(h: jax.Array, *, tile: int = TILE,
         remap = lambda i, j, kb=kb: ((kb + i) % nb, (kb + j) % nb)
         h, _, _ = pl.pallas_call(
             functools.partial(_fw_round_kernel, kb, nb),
+            name="floyd_warshall_pallas",
             grid=(nb, nb),
             in_specs=[pl.BlockSpec((t, t), remap)],
             out_specs=[pl.BlockSpec((t, t), remap),

@@ -94,6 +94,7 @@ def fused_adjacency_pallas(u: jax.Array, scal: jax.Array, *, n: int,
     ut = u.T.copy()
     return pl.pallas_call(
         functools.partial(_fused_kernel, n, clamp),
+        name="fused_adjacency_pallas",
         grid=grid,
         in_specs=[pl.BlockSpec((tile_n, d), lambda p, i, j: (i, 0)),
                   pl.BlockSpec((d, tile_n), lambda p, i, j: (0, j)),

@@ -58,6 +58,7 @@ def krum_pallas(x: jax.Array, *, tile_m: int = KRUM_TM,
     grid = (m // tile_m, m // tile_m, p // tile_k)
     return pl.pallas_call(
         _krum_kernel,
+        name="krum_pallas",
         grid=grid,
         in_specs=[pl.BlockSpec((tile_m, tile_k), lambda i, j, k: (i, k)),
                   pl.BlockSpec((tile_k, tile_m), lambda i, j, k: (k, j))],

@@ -40,6 +40,7 @@ def similarity_pallas(u: jax.Array, *, tile_n: int = TILE_N,
     grid = (n // tile_n, n // tile_n, d // tile_k)
     return pl.pallas_call(
         _sim_kernel,
+        name="similarity_pallas",
         grid=grid,
         in_specs=[pl.BlockSpec((tile_n, tile_k), lambda i, j, k: (i, k)),
                   pl.BlockSpec((tile_k, tile_n), lambda i, j, k: (k, j))],
@@ -71,6 +72,7 @@ def adjacency_pallas(v: jax.Array, scalars: jax.Array, *, tile_n: int = TILE_N,
     scalars = scalars.reshape(1, 4).astype(jnp.float32)
     return pl.pallas_call(
         _adj_kernel,
+        name="adjacency_pallas",
         grid=grid,
         in_specs=[pl.BlockSpec((tile_n, tile_n), lambda i, j: (i, j)),
                   pl.BlockSpec((1, 4), lambda i, j: (0, 0))],

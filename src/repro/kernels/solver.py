@@ -117,6 +117,7 @@ def masked_argmax_pallas(diag: jax.Array, r: jax.Array, mask: jax.Array, *,
     assert n % tile == 0 and r.shape == diag.shape == mask.shape
     return pl.pallas_call(
         _masked_argmax_kernel,
+        name="masked_argmax_pallas",
         grid=(n // tile,),
         in_specs=[pl.BlockSpec((1, tile), lambda b: (0, b)),
                   pl.BlockSpec((1, tile), lambda b: (0, b)),
@@ -180,6 +181,7 @@ def swap_gain_pallas(qs: jax.Array, a: jax.Array, b: jax.Array, *,
     grid = (m // tile_m, n // tile_n)
     return pl.pallas_call(
         _swap_gain_kernel,
+        name="swap_gain_pallas",
         grid=grid,
         in_specs=[pl.BlockSpec((tile_m, 1), lambda i, j: (i, 0)),
                   pl.BlockSpec((1, tile_n), lambda i, j: (0, j)),
@@ -233,6 +235,7 @@ def swap_gain_fused_pallas(hs: jax.Array, hts: jax.Array, a: jax.Array,
     grid = (m // tile_m, n // tile_n)
     return pl.pallas_call(
         _swap_fused_kernel,
+        name="swap_gain_fused_pallas",
         grid=grid,
         in_specs=[pl.BlockSpec((tile_m, 1), lambda i, j: (i, 0)),
                   pl.BlockSpec((1, tile_n), lambda i, j: (0, j)),

@@ -364,7 +364,7 @@ def main():
                   aggregator=args.aggregator, agg_backend=args.agg_backend,
                   sweep_mesh=sweep, tracer=tracer, sink=sink)
     finally:
-        trace = finish_observability(tracer, sink, args)
+        trace = finish_observability(sink, args)
         if trace:
             print(f"trace: {trace}")
     raise SystemExit(0 if rec["ok"] else 1)

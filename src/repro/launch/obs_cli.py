@@ -1,33 +1,32 @@
 """Shared observability CLI knobs (DESIGN.md §17).
 
 Every launch entry point (``train.py``, ``fedsim.py``, ``serve.py``)
-exposes the same three flags through ``add_observability_args``::
+exposes the same two flags through ``add_observability_args``::
 
-    --trace-dir DIR      record host spans; Chrome trace.json lands in DIR
-    --profile            also arm jax.profiler (XLA trace in DIR/xla)
+    --trace-dir DIR      record one jax.profiler trace into DIR: the host
+                         spans and the device's ops on one clock
     --metrics-jsonl F    stream schema-versioned metric events to F
 
-``make_observability`` builds the (tracer, sink) pair from parsed args;
-``finish_observability`` exports the Chrome trace, stops the profiler
-and drains/closes the sink — call it in a ``finally``.
+``make_observability`` builds the (tracer, sink) pair from parsed args and
+starts the trace; ``finish_observability`` stops the trace and
+drains/closes the sink — call it in a ``finally``.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
-from repro.fed.telemetry import Tracer, make_tracer
+import jax
+
+from repro.fed.telemetry import make_tracer
 from repro.obs import JSONLMetricsSink
 
 
 def add_observability_args(ap):
     g = ap.add_argument_group("observability")
     g.add_argument("--trace-dir", default=None,
-                   help="record host spans; writes trace.json here "
-                        "(load in chrome://tracing / ui.perfetto.dev)")
-    g.add_argument("--profile", action="store_true",
-                   help="also record a jax.profiler XLA trace under "
-                        "<trace-dir>/xla")
+                   help="record a jax.profiler trace here: host spans and "
+                        "device ops on one clock (a perfetto_trace.json.gz "
+                        "for ui.perfetto.dev lands beside the .xplane.pb)")
     g.add_argument("--metrics-jsonl", default=None,
                    help="stream schema-versioned metric events (JSONL) "
                         "to this file")
@@ -36,25 +35,28 @@ def add_observability_args(ap):
 
 def make_observability(args, *, run: Optional[str] = None):
     """(tracer, sink) from parsed args — NULL_TRACER / None when the
-    flags are off, so call sites pass them through unconditionally."""
+    flags are off, so call sites pass them through unconditionally.
+    With ``--trace-dir`` the profiler trace starts here."""
     trace_dir = getattr(args, "trace_dir", None)
-    profile = bool(getattr(args, "profile", False))
-    tracer = make_tracer(trace_dir, profile)
-    if profile:
-        tracer.start_profiler()
+    tracer = make_tracer(trace_dir)
+    if trace_dir:
+        # the engine's spans are the host events: the Python function
+        # tracer would bury them under every interpreter call
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, create_perfetto_trace=True,
+                                 profiler_options=opts)
     metrics = getattr(args, "metrics_jsonl", None)
     sink = JSONLMetricsSink(metrics, run=run) if metrics else None
     return tracer, sink
 
 
-def finish_observability(tracer: Tracer, sink, args) -> Optional[str]:
-    """Export the Chrome trace (returns its path), stop the profiler,
-    drain + close the sink.  Safe to call with observability off."""
-    path = None
-    tracer.stop_profiler()
+def finish_observability(sink, args) -> Optional[str]:
+    """Stop the profiler trace (returns its directory), drain + close the
+    sink.  Safe to call with observability off."""
     trace_dir = getattr(args, "trace_dir", None)
-    if trace_dir and tracer.enabled:
-        path = tracer.export_chrome(os.path.join(trace_dir, "trace.json"))
+    if trace_dir:
+        jax.profiler.stop_trace()
     if sink is not None:
         sink.close()
-    return path
+    return trace_dir

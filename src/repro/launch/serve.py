@@ -244,7 +244,7 @@ def _fedsim_main(args):
               f"{st['compile_ms']:.0f} ms), {st['hits']} cache hits")
         print(svc.metrics_text(), end="")
     finally:
-        trace = finish_observability(tracer, sink, args)
+        trace = finish_observability(sink, args)
         if trace:
             print(f"trace: {trace}")
     return [svc.histories[t] for t in tickets]

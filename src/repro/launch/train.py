@@ -239,7 +239,7 @@ def main(argv=None):
                                     "round": np.asarray(t, np.int64)},
                         metadata={"round": t, "arch": cfg.name})
     finally:
-        trace = finish_observability(tracer, sink, args)
+        trace = finish_observability(sink, args)
         if trace:
             print(f"trace: {trace}")
     print(f"done in {time.time() - t0:.1f}s; final Var(v^t)={count_variance(counts):.3f}")

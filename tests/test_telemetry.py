@@ -13,9 +13,9 @@ Pinned claims:
   is positive while clean cells read 0;
 * a resumed run's pre-resume telemetry prefix is NaN (telemetry is
   observability, not state — it is NOT checkpointed);
-* the host-side ``Tracer`` nests spans, summarizes per-name, and exports
-  a loadable Chrome/Perfetto ``trace.json``; the NULL_TRACER records
-  nothing but still enters ``jax.named_scope``;
+* the host-side ``Tracer`` nests spans and summarizes per-name in bounded
+  memory, and its spans land in the ``jax.profiler`` trace as host events
+  with their attributes; the NULL_TRACER records nothing;
 * ``JSONLMetricsSink`` round-trips schema-versioned events in order and
   ``read_metrics_jsonl`` rejects unknown schema versions;
 * ``render_prometheus`` emits valid exposition text (TYPE/HELP + labeled
@@ -25,8 +25,10 @@ Pinned claims:
 * ``SimService`` stamps submit -> first-segment and submit -> complete
   latency per request and serves them through ``metrics_text()``.
 """
+import glob
 import json
 import math
+import os
 
 import numpy as np
 import pytest
@@ -257,30 +259,45 @@ class TestTracer:
                 pass
             with tr.span("inner"):
                 pass
-        evs = tr.events()
-        assert [e["name"] for e in evs] == ["inner", "inner", "outer"]
-        depths = {e["name"]: e["depth"] for e in evs}
-        assert depths == {"inner": 1, "outer": 0}
         s = tr.summary()
+        assert set(s) == {"inner", "outer"}
         assert s["inner"]["count"] == 2 and s["outer"]["count"] == 1
         assert s["outer"]["total_ms"] >= s["inner"]["total_ms"]
-        assert evs[-1]["args"]["tag"] == "x"
+        assert s["inner"]["max_ms"] <= s["inner"]["total_ms"]
 
-    def test_export_chrome_loads(self, tmp_path):
-        tr = Tracer()
-        with tr.span("a"):
-            pass
-        p = tr.export_chrome(str(tmp_path / "trace.json"))
-        doc = json.loads(open(p).read())
-        (ev,) = doc["traceEvents"]
-        assert ev["ph"] == "X" and ev["name"] == "a"
-        assert ev["dur"] >= 0 and "ts" in ev
-        assert doc["displayTimeUnit"] == "ms"
+    def test_spans_land_in_the_profiler_trace(self, tmp_path):
+        """An enabled tracer's spans are host events of the jax.profiler
+        trace, with their attributes; the NULL_TRACER's are not."""
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            tr = Tracer()
+            with tr.span("dispatch_segment", t0=5, rounds=2):
+                jnp.ones(4).block_until_ready()
+            with NULL_TRACER.span("null_span"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        evs = _host_events(tmp_path)
+        (ev,) = [e for e in evs if e.name == "dispatch_segment"]
+        assert dict(ev.stats) == {"t0": 5, "rounds": 2}
+        assert ev.duration_ns > 0
+        assert not [e for e in evs if e.name == "null_span"]
 
     def test_null_tracer_records_nothing(self):
         with NULL_TRACER.span("x"):
             pass
-        assert NULL_TRACER.events() == [] and NULL_TRACER.summary() == {}
+        assert NULL_TRACER.summary() == {}
+        assert "spans" not in runtime_snapshot(tracer=NULL_TRACER)
+
+    def test_summary_stays_bounded(self):
+        tr = Tracer()
+        for i in range(10_000):
+            with tr.span(("a", "b", "c")[i % 3], i=i):
+                pass
+        s = tr.summary()
+        assert sorted(s) == ["a", "b", "c"]
+        assert sum(v["count"] for v in s.values()) == 10_000
+        assert len(tr._stats) == 3
 
     def test_span_exception_still_recorded(self):
         tr = Tracer()
@@ -290,9 +307,20 @@ class TestTracer:
         assert tr.summary()["boom"]["count"] == 1
 
     def test_make_tracer_gating(self, tmp_path):
-        assert make_tracer(None, False) is NULL_TRACER
-        tr = make_tracer(str(tmp_path), False)
+        assert make_tracer(None) is NULL_TRACER
+        tr = make_tracer(str(tmp_path))
         assert tr.enabled and tr is not NULL_TRACER
+
+
+def _host_events(trace_dir):
+    """The host-thread events of the jax.profiler trace under
+    ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    return [e for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
 
 
 # ------------------------------------------------------------------- sinks
@@ -433,7 +461,8 @@ def test_sim_service_request_latency_and_metrics_text(ds16):
 
 def test_fedsim_cli_with_observability(tmp_path, capsys):
     """serve --fedsim end-to-end with every observability knob on: JSONL
-    metrics + chrome trace land on disk, prometheus text prints."""
+    metrics and one jax.profiler trace (the engine's host spans among its
+    events) land on disk, prometheus text prints."""
     from repro.launch import serve
     mpath = tmp_path / "m.jsonl"
     tdir = tmp_path / "traces"
@@ -445,8 +474,10 @@ def test_fedsim_cli_with_observability(tmp_path, capsys):
     evs = read_metrics_jsonl(str(mpath))
     assert {"run_start", "round", "request", "run_end"} <= \
         {e["kind"] for e in evs}
-    trace = json.loads((tdir / "trace.json").read_text())
-    assert any(e["name"] == "dispatch_segment"
-               for e in trace["traceEvents"])
+    evs = _host_events(tdir)
+    dispatch = [e for e in evs if e.name == "dispatch_segment"]
+    assert dispatch and all(e.duration_ns > 0 for e in dispatch)
+    assert glob.glob(str(tdir / "**" / "perfetto_trace.json.gz"),
+                     recursive=True)
     out = capsys.readouterr().out
     assert "fedgs_requests_total" in out
