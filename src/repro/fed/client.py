@@ -4,21 +4,42 @@ This replaces the paper's sequential PyTorch client loop with a single
 client-batched program (the TPU-native formulation, DESIGN.md §3): all
 sampled clients' padded data is stacked and E local SGD steps run under
 ``vmap`` with per-client batch draws.
+
+Under an enclosing ``vmap`` (the scan engine's cells) the trainer folds
+the enclosing axis into its own client axis, so the program trains one
+flat axis of cells x clients instead of a vmap nested in a vmap: nested,
+every convolution is batched twice and XLA copies each conv activation
+and cotangent between the grouped conv's layout and the client-major one
+(DESIGN.md §3).  The per-client maths is the same either way.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
-from repro.utils.tree import global_norm
+
+def _spread(a, batched, per_client, size, k):
+    """One argument of a fold: (size, k, ...) -> (size * k, ...).  An
+    unbatched argument is broadcast over the enclosing axis, and a shared
+    one (no client axis) over the k clients."""
+    if not batched:
+        a = jnp.broadcast_to(a, (size,) + a.shape)
+    if not per_client:
+        a = jnp.broadcast_to(a[:, None], (size, k) + a.shape[1:])
+    return a.reshape((size * k,) + a.shape[2:])
 
 
 def make_local_trainer(model_loss, *, local_steps: int, batch_size: int,
                        prox_mu: float = 0.0):
     """Returns jit'd fn(global_params, x (M,n_max,...), y (M,n_max), sizes (M,),
-    lr, rng) -> stacked local params (M, ...)."""
+    lr, rng) -> stacked local params (M, ...).
+
+    Its ``fold_stats`` dict is the trace-time record of the fold: how many
+    times an enclosing vmap was folded into the client axis (once per vmap
+    level around each traced call, again where a scan whose carry turns
+    batched batches its body a second time) and the widest folded axis."""
+    fold_stats = {"folds": 0, "clients": 0}
 
     def one_client(global_params, x, y, n_k, lr, rng):
         def loss_fn(p, xb, yb):
@@ -40,8 +61,42 @@ def make_local_trainer(model_loss, *, local_steps: int, batch_size: int,
                                  jax.random.split(rng, local_steps))
         return params
 
-    batched = jax.vmap(one_client, in_axes=(None, 0, 0, 0, None, 0))
-    return jax.jit(batched)
+    def clients(params_axis):
+        """vmap(one_client) over K clients whose start params are shared
+        (``params_axis`` None) or per client (0); ``lr`` is shared as a
+        scalar or per client as (K,).  Nothing differentiates through the
+        trainer: a custom_vmap function has no reverse-mode derivative."""
+        @custom_vmap
+        def train(params, x, y, sizes, lr, rngs):
+            lr_axis = 0 if jnp.ndim(lr) else None
+            return jax.vmap(one_client, in_axes=(
+                params_axis, 0, 0, 0, lr_axis, 0))(params, x, y, sizes, lr,
+                                                   rngs)
+
+        @train.def_vmap
+        def fold(size, in_batched, params, x, y, sizes, lr, rngs):
+            p_b, x_b, y_b, s_b, lr_b, r_b = in_batched
+            k = sizes.shape[1] if s_b else sizes.shape[0]
+            fold_stats["folds"] += 1
+            fold_stats["clients"] = max(fold_stats["clients"], size * k)
+            params = jax.tree_util.tree_map(
+                lambda a, b: _spread(a, b, params_axis == 0, size, k),
+                params, p_b)
+            if lr_b or jnp.ndim(lr):
+                lr = _spread(lr, lr_b, jnp.ndim(lr) == lr_b + 1, size, k)
+            x, y, sizes, rngs = (_spread(a, b, True, size, k) for a, b in
+                                 ((x, x_b), (y, y_b), (sizes, s_b),
+                                  (rngs, r_b)))
+            out = per_client_train(params, x, y, sizes, lr, rngs)
+            out = jax.tree_util.tree_map(
+                lambda a: a.reshape((size, k) + a.shape[1:]), out)
+            return out, jax.tree_util.tree_map(lambda _: True, out)
+        return train
+
+    per_client_train = clients(0)
+    trainer = jax.jit(clients(None))
+    trainer.fold_stats = fold_stats
+    return trainer
 
 
 def make_loss_prober(model_loss, *, probe_size: int = 64):

@@ -545,7 +545,8 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
             sel, valid = select(s)
             sel_out = sel.astype(jnp.int32)
 
-        # 3. vmap'd local training on the M gathered clients — under a
+        # 3. vmap'd local training on the M gathered clients (the cell
+        # vmap folds into the trainer's client axis: cells x M) — under a
         # silo'd mesh each shard trains its ceil(M/s) chunk (same
         # per-client keys) and all_gathers the stacked updates
         with jax.named_scope("local_train"):
@@ -663,7 +664,8 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
         return {"params": carry["agg"]["prev"], "counts": carry["counts"],
                 **traj}
 
-    return {"init": init, "segment": segment, "simulate": simulate}
+    return {"init": init, "segment": segment, "simulate": simulate,
+            "fold_stats": trainer.fold_stats}
 
 
 # ------------------------------------------------------------------- engine
@@ -696,11 +698,16 @@ class ScanEngine:
         """The unified telemetry snapshot (DESIGN.md §17): program-cache
         counters FLAT at the top level (hits, misses, evictions, compiles,
         compile_ms, size — the pre-telemetry shape benchmarks read), plus
-        the last run's checkpoint-writer backpressure counters and the
-        tracer's per-span aggregates."""
+        the last run's checkpoint-writer backpressure counters, the
+        tracer's per-span aggregates and the trace-time local-training
+        fold counter of this engine's programs (``make_local_trainer``)."""
+        folds = [sim["fold_stats"] for sim in self._sims.values()]
+        fold = {"folds": sum(f["folds"] for f in folds),
+                "clients": max((f["clients"] for f in folds), default=0)}
         return runtime_snapshot(programs=self._programs,
                                 writer=self._writer_stats,
-                                tracer=self.tracer)
+                                tracer=self.tracer,
+                                extra={"local_train_fold": fold})
 
     def attach_sink(self, sink):
         """Install (or clear, with ``None``) the streaming metrics sink —

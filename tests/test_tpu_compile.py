@@ -7,7 +7,8 @@ clients, m=64 sampled, the small CNN's P=13946 flat params), to the TPU
 compiler for a described, unattached ``v5e:2x2`` topology, through the same
 ``kernels/ops.py`` wrappers (padding included) the engine calls.  Nothing
 runs: a pass means the chip's compiler accepts the kernel and lowers it to
-a native ``tpu_custom_call``.
+a native ``tpu_custom_call``.  One more case compiles the cell-vmapped
+local trainer and compares the bytes it moves with the nested vmap's.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and the fixture skips the
@@ -106,3 +107,47 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, chip_tiles):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{kernel} did not lower to a native TPU kernel"
+
+
+def _cifar_cnn_loss(p, x, y):
+    """Two 5x5 convolutions of 64 with ReLU and 2x2 max pool, then the
+    logits: the convolutions of the benchmark's CIFAR-10 CNN."""
+    for w, b in (("c1", "b1"), ("c2", "b2")):
+        x = jax.lax.conv_general_dilated(
+            x, p[w], (1, 1), "VALID",
+            dimension_numbers=("NHWC", "HWIO", "NHWC")) + p[b]
+        x = jax.lax.reduce_window(jax.nn.relu(x), -jnp.inf, jax.lax.max,
+                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+    logits = x.reshape(x.shape[0], -1) @ p["d"] + p["db"]
+    return -jnp.mean(jnp.take_along_axis(jax.nn.log_softmax(logits),
+                                         y[:, None], axis=1))
+
+
+def test_folded_local_training_bytes_for_v5e(one_chip):
+    """The cell-vmapped trainer at 32x32x3 compiles for the chip and reads
+    and writes at most 0.6x the bytes of the nested vmap (which copies each
+    conv activation and cotangent between layouts)."""
+    from test_local_train_fold import nested_trainer
+
+    from repro.fed.client import make_local_trainer
+    cells, clients, n_max, batch = 3, 4, 40, 16
+    f32 = jnp.float32
+
+    def s(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = {"c1": s((cells, 5, 5, 3, 64)), "b1": s((cells, 64)),
+              "c2": s((cells, 5, 5, 64, 64)), "b2": s((cells, 64)),
+              "d": s((cells, 5 * 5 * 64, 10)), "db": s((cells, 10))}
+    args = (params, s((cells, clients, n_max, 32, 32, 3)),
+            s((cells, clients, n_max), jnp.int32),
+            s((cells, clients), jnp.int32), s(()),
+            s((cells, clients, 2), jnp.uint32))
+
+    def bytes_accessed(factory):
+        trainer = factory(_cifar_cnn_loss, local_steps=2, batch_size=batch)
+        compiled = jax.jit(jax.vmap(trainer, in_axes=(0, 0, 0, 0, None, 0))
+                           ).lower(*args).compile()
+        cost = compiled.cost_analysis()
+        return (cost[0] if isinstance(cost, list) else cost)["bytes accessed"]
+    assert bytes_accessed(make_local_trainer) <= \
+        0.6 * bytes_accessed(nested_trainer)
