@@ -35,6 +35,8 @@ class SSMConfig:
     expand: int = 2
     chunk: int = 256
     d_conv: int = 4
+    conv_bias: bool = False          # bias on the causal conv (Mamba-2 as published)
+    gated_norm: bool = False         # norm(y * silu(z)) before out_proj, not y * silu(z)
 
 
 @dataclass(frozen=True)
@@ -55,6 +57,16 @@ class ArchConfig:
     attention: str = "full"          # full | sliding_window | none
     window: int = 4096               # used when attention == sliding_window
     rope_theta: float = 10000.0
+    position_embedding: str = "rope" # rope | nope (no positional encoding)
+    attn_scale: Optional[float] = None   # None: 1/sqrt(head_dim)
+    # --- mixed stacks (granite-4.0-h): one "mamba" | "attention" mixer per
+    # layer, each followed by the FFN; None = every layer the same block
+    layer_types: Optional[tuple] = None
+    # --- muP-style multipliers: input embeddings x, each residual branch x,
+    # logits / (1.0 each = none)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # --- optional sub-configs ---
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -89,6 +101,9 @@ class ArchConfig:
         if not self.tie_embeddings:
             total += V * d                 # lm head
         total += d                         # final norm
+        if self.layer_types is not None:
+            return int(total + sum(self.layer_param_count(k)
+                                   for k in self.layer_types))
         per_layer = 0
         if self.attention != "none":
             qd = self.n_heads * self.head_dim
@@ -126,6 +141,25 @@ class ArchConfig:
             total += L * (d * qd + 2 * d * kvd + qd * d + d)
         return int(total)
 
+    def layer_param_count(self, kind: str) -> int:
+        """Parameters of one layer of a mixed stack: its mixer ("mamba" or
+        "attention") with the mixer's norm, then the FFN with its norm."""
+        d = self.d_model
+        if kind == "attention":
+            qd = self.n_heads * self.head_dim
+            kvd = self.n_kv_heads * self.head_dim
+            mixer = d * qd + 2 * d * kvd + qd * d
+        else:
+            s = self.ssm
+            d_in = s.expand * d
+            nheads = d_in // s.head_dim
+            conv_ch = d_in + 2 * s.d_state
+            mixer = d * (2 * d_in + 2 * s.d_state + nheads) + 3 * nheads
+            mixer += conv_ch * s.d_conv + d_in * d
+            mixer += conv_ch * s.conv_bias + d_in * s.gated_norm
+        n_mats = 3 if self.ffn_kind == "swiglu" else 2
+        return mixer + n_mats * d * self.d_ff + 2 * d
+
     def active_param_count(self) -> int:
         """Params touched per token (MoE: only top_k experts active)."""
         if self.moe is None:
@@ -147,7 +181,12 @@ class ArchConfig:
             moe = MoEConfig(num_experts=4, top_k=2, capacity_factor=self.moe.capacity_factor)
         ssm = None
         if self.ssm is not None:
-            ssm = SSMConfig(d_state=16, head_dim=32, expand=2, chunk=32, d_conv=4)
+            ssm = SSMConfig(d_state=16, head_dim=32, expand=2, chunk=32, d_conv=4,
+                            conv_bias=self.ssm.conv_bias,
+                            gated_norm=self.ssm.gated_norm)
+        layer_types = None
+        if self.layer_types is not None:      # one layer of each kind
+            layer_types = tuple(dict.fromkeys(self.layer_types))
         return dataclasses.replace(
             self,
             n_layers=2,
@@ -164,6 +203,7 @@ class ArchConfig:
             n_audio_frames=min(self.n_audio_frames, 16),
             window=min(self.window, 64),
             dtype="float32",
+            layer_types=layer_types,
         )
 
 

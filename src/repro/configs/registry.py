@@ -14,6 +14,7 @@ from repro.configs.nemotron_4_15b import CONFIG as _nem15
 from repro.configs.smollm_135m import CONFIG as _smollm
 from repro.configs.mamba2_780m import CONFIG as _mamba2
 from repro.configs.granite_moe_1b_a400m import CONFIG as _granite
+from repro.configs.granite_4_0_h_micro import CONFIG as _granite_h
 from repro.configs.deepseek_coder_33b import CONFIG as _dsc
 from repro.configs.hymba_1_5b import CONFIG as _hymba
 
@@ -21,7 +22,7 @@ REGISTRY: dict[str, ArchConfig] = {
     c.name: c
     for c in [
         _llava, _seamless, _olmoe, _nem340, _nem15,
-        _smollm, _mamba2, _granite, _dsc, _hymba,
+        _smollm, _mamba2, _granite, _dsc, _hymba, _granite_h,
     ]
 }
 

@@ -320,7 +320,8 @@ _fedgs_select = partial(jax.jit, static_argnames=(
 # ------------------------------------------------------- the switch step
 def make_sampler_step(n: int, m: int, *, max_sweeps: int = 32,
                       d_cand: int | None = None, probe_losses=None,
-                      solver_backend: str = "ref"):
+                      solver_backend: str = "ref",
+                      families: tuple | None = None):
     """Compile-time constructor of the ONE per-round sampler step
 
         ``step(sparams, state, key, inputs, avail, t) -> (s, state)``
@@ -375,7 +376,22 @@ def make_sampler_step(n: int, m: int, *, max_sweeps: int = 32,
                               [branches[f] for f in FAMILIES],
                               sparams, state, key, inputs, avail, t)
 
-    return step
+    if families is None:
+        return step
+    families = tuple(families)
+    if not families or any(f not in FAMILIES for f in families):
+        raise ValueError(f"families must be a non-empty subset of "
+                         f"{FAMILIES}, not {families!r}")
+    if len(families) == 1:
+        return branches[families[0]]
+    slot = np.asarray([families.index(f) if f in families else 0
+                       for f in FAMILIES], np.int32)
+
+    def subset_step(sparams, state, key, inputs, avail, t):
+        return jax.lax.switch(jnp.asarray(slot)[sparams["family"]],
+                              [branches[f] for f in families],
+                              sparams, state, key, inputs, avail, t)
+    return subset_step
 
 
 # ------------------------------------------------------------ the processes

@@ -308,6 +308,85 @@ def krum_combine(updf, valid, f_byz, multi, *, backend: str = "ref",
     return out, chosen, scores
 
 
+# ------------------------------------------ updates from the Eq. 18 mean
+def _momentum_step(ap, state, avg):
+    """FedAvgM: server momentum on the pseudo-gradient ``prev - avg`` (a
+    zero-weight round contributes a zero pseudo-gradient: the momentum
+    keeps decaying, the params keep drifting along it)."""
+    lr_s, beta = ap["theta"][0], ap["theta"][1]
+    m1 = jax.tree_util.tree_map(
+        lambda mo, p0, a: beta * mo + (p0 - a), state["m1"],
+        state["prev"], avg)
+    new = jax.tree_util.tree_map(
+        lambda p0, mo: p0 - lr_s * mo, state["prev"], m1)
+    return new, {**state, "prev": new, "m1": m1}
+
+
+def _adam_step(ap, state, avg):
+    """Reddi et al. 2021 FedAdam (no bias correction, per the paper)."""
+    lr_s, b1, b2 = ap["theta"][0], ap["theta"][1], ap["theta"][2]
+    eps = ap["theta"][3]
+    delta = jax.tree_util.tree_map(
+        lambda a, p0: a - p0, avg, state["prev"])
+    m1 = jax.tree_util.tree_map(
+        lambda mo, d: b1 * mo + (1.0 - b1) * d, state["m1"], delta)
+    m2 = jax.tree_util.tree_map(
+        lambda vo, d: b2 * vo + (1.0 - b2) * d * d, state["m2"], delta)
+    new = jax.tree_util.tree_map(
+        lambda p0, mo, vo: p0 + lr_s * mo / (jnp.sqrt(vo) + eps),
+        state["prev"], m1, m2)
+    return new, {**state, "prev": new, "m1": m1, "m2": m2}
+
+
+# the families whose update reads the clients only through the Eq. 18
+# mean, so that mean can be streamed client by client
+MEAN_FAMILIES = ("fedavg", "fedavgm", "fedadam")
+
+
+def init_mean_agg_state(params0, families) -> dict:
+    """The carried state of a streamed-mean program (``make_mean_aggregator
+    _step``): ``prev``, with ``m1`` only where FedAvgM or FedAdam is among
+    ``families`` and ``m2`` only where FedAdam is."""
+    state = {"prev": params0}
+    if {"fedavgm", "fedadam"} & set(families):
+        state["m1"] = jax.tree_util.tree_map(jnp.zeros_like, params0)
+    if "fedadam" in families:
+        state["m2"] = jax.tree_util.tree_map(jnp.zeros_like, params0)
+    return state
+
+
+def make_mean_aggregator_step(families):
+    """The server update of a program that streams the Eq. 18 mean
+    (``fed/client.make_sequential_trainer``) instead of stacking the
+    clients' models:
+
+        ``step(aparams, state, avg) -> (params, state)``
+
+    ``avg`` is the zero-weight-guarded Eq. 18 mean.  Only ``families``
+    (a subset of ``MEAN_FAMILIES``, the families of the batch's cells) are
+    built: one family is called directly, several switch on the cell's
+    family index.  The FedAvgM and FedAdam updates are the switch
+    branches' own (``_momentum_step``, ``_adam_step``)."""
+    families = tuple(families)
+    other = [f for f in families if f not in MEAN_FAMILIES]
+    if other or not families:
+        raise ValueError(f"a streamed Eq. 18 mean serves {MEAN_FAMILIES}, "
+                         f"not {tuple(other) or families}")
+    branches = {"fedavg": lambda ap, state, avg: (avg, {**state,
+                                                        "prev": avg}),
+                "fedavgm": _momentum_step, "fedadam": _adam_step}
+    if len(families) == 1:
+        return branches[families[0]]
+    slot = np.asarray([families.index(f) if f in families else 0
+                       for f in FAMILIES], np.int32)
+
+    def step(aparams, state, avg):
+        return jax.lax.switch(jnp.asarray(slot)[aparams["family"]],
+                              [branches[f] for f in families],
+                              aparams, state, avg)
+    return step
+
+
 # ------------------------------------------------------- the switch step
 def make_aggregator_step(n: int, m: int, params_like, *, data_sizes=None,
                          backend: str = "ref",
@@ -367,33 +446,11 @@ def make_aggregator_step(n: int, m: int, params_like, *, data_sizes=None,
         return new, {**state, "prev": new}
 
     def _fedavgm(ap, state, key, upd, w, s, avail, t, sel, valid):
-        """Server momentum on the pseudo-gradient ``prev - avg`` (a
-        zero-weight round contributes a zero pseudo-gradient: the momentum
-        keeps decaying, the params keep drifting along it)."""
-        lr_s, beta = ap["theta"][0], ap["theta"][1]
-        avg = fedavg_combine(upd, w, state["prev"])
-        m1 = jax.tree_util.tree_map(
-            lambda mo, p0, a: beta * mo + (p0 - a), state["m1"],
-            state["prev"], avg)
-        new = jax.tree_util.tree_map(
-            lambda p0, mo: p0 - lr_s * mo, state["prev"], m1)
-        return new, {**state, "prev": new, "m1": m1}
+        return _momentum_step(ap, state, fedavg_combine(upd, w,
+                                                        state["prev"]))
 
     def _fedadam(ap, state, key, upd, w, s, avail, t, sel, valid):
-        """Reddi et al. 2021 FedAdam (no bias correction, per the paper)."""
-        lr_s, b1, b2 = ap["theta"][0], ap["theta"][1], ap["theta"][2]
-        eps = ap["theta"][3]
-        avg = fedavg_combine(upd, w, state["prev"])
-        delta = jax.tree_util.tree_map(
-            lambda a, p0: a - p0, avg, state["prev"])
-        m1 = jax.tree_util.tree_map(
-            lambda mo, d: b1 * mo + (1.0 - b1) * d, state["m1"], delta)
-        m2 = jax.tree_util.tree_map(
-            lambda vo, d: b2 * vo + (1.0 - b2) * d * d, state["m2"], delta)
-        new = jax.tree_util.tree_map(
-            lambda p0, mo, vo: p0 + lr_s * mo / (jnp.sqrt(vo) + eps),
-            state["prev"], m1, m2)
-        return new, {**state, "prev": new, "m1": m1, "m2": m2}
+        return _adam_step(ap, state, fedavg_combine(upd, w, state["prev"]))
 
     def _fedprox_w(ap, state, key, upd, w, s, avail, t, sel, valid):
         """Eq. 18 with each weight damped by the client's squared drift from

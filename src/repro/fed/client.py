@@ -11,12 +11,19 @@ flat axis of cells x clients instead of a vmap nested in a vmap: nested,
 every convolution is batched twice and XLA copies each conv activation
 and cotangent between the grouped conv's layout and the client-major one
 (DESIGN.md §3).  The per-client maths is the same either way.
+
+A client model too large for an (M, P) stack of client models trains client
+by client instead (``make_sequential_trainer``): a ``lax.scan`` over the M
+sampled clients, each trained from the global params by the same per-client
+maths, streams the Eq. 18 weighted sum into one accumulator.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
+
+from repro.fed.aggregator_device import guard_zero_weight
 
 
 def _spread(a, batched, per_client, size, k):
@@ -30,16 +37,11 @@ def _spread(a, batched, per_client, size, k):
     return a.reshape((size * k,) + a.shape[2:])
 
 
-def make_local_trainer(model_loss, *, local_steps: int, batch_size: int,
-                       prox_mu: float = 0.0):
-    """Returns jit'd fn(global_params, x (M,n_max,...), y (M,n_max), sizes (M,),
-    lr, rng) -> stacked local params (M, ...).
-
-    Its ``fold_stats`` dict is the trace-time record of the fold: how many
-    times an enclosing vmap was folded into the client axis (once per vmap
-    level around each traced call, again where a scan whose carry turns
-    batched batches its body a second time) and the widest folded axis."""
-    fold_stats = {"folds": 0, "clients": 0}
+def _client_update(model_loss, local_steps: int, batch_size: int,
+                   prox_mu: float):
+    """fn(global_params, x (n_max,...), y, n_k, lr, rng) -> the client's
+    params after ``local_steps`` SGD steps on minibatches of
+    ``batch_size`` drawn with replacement, one key per step."""
 
     def one_client(global_params, x, y, n_k, lr, rng):
         def loss_fn(p, xb, yb):
@@ -60,6 +62,21 @@ def make_local_trainer(model_loss, *, local_steps: int, batch_size: int,
         params, _ = jax.lax.scan(step, global_params,
                                  jax.random.split(rng, local_steps))
         return params
+
+    return one_client
+
+
+def make_local_trainer(model_loss, *, local_steps: int, batch_size: int,
+                       prox_mu: float = 0.0):
+    """Returns jit'd fn(global_params, x (M,n_max,...), y (M,n_max), sizes (M,),
+    lr, rng) -> stacked local params (M, ...).
+
+    Its ``fold_stats`` dict is the trace-time record of the fold: how many
+    times an enclosing vmap was folded into the client axis (once per vmap
+    level around each traced call, again where a scan whose carry turns
+    batched batches its body a second time) and the widest folded axis."""
+    fold_stats = {"folds": 0, "clients": 0}
+    one_client = _client_update(model_loss, local_steps, batch_size, prox_mu)
 
     def clients(params_axis):
         """vmap(one_client) over K clients whose start params are shared
@@ -97,6 +114,63 @@ def make_local_trainer(model_loss, *, local_steps: int, batch_size: int,
     trainer = jax.jit(clients(None))
     trainer.fold_stats = fold_stats
     return trainer
+
+
+def make_sequential_trainer(model_loss, *, local_steps: int,
+                            batch_size: int, prox_mu: float = 0.0):
+    """Returns jit'd fn(global_params, x (N,n_max,...), y (N,n_max), sizes
+    (N,), sel (M,), lr, rngs (M, ...), weights (M,)) -> the Eq. 18 mean
+    ``sum_k w_k theta_k / sum_k w_k``.
+
+    A ``lax.scan`` over the M clients ``sel``: each starts from the global
+    params and takes the same SGD steps, on the same minibatch draws, as in
+    ``make_local_trainer``; its params, times its normalised weight, are
+    added into one float32 accumulator (scope ``accumulate``), so the
+    program holds the global params, one client's params, its gradient and
+    the accumulator, and never an (M, P) stack.  A round whose weights are
+    all zero returns the global params (``guard_zero_weight``).
+
+    Under an enclosing vmap the cells train one after another too (a unit
+    axis is dropped, not batched into every matmul); nothing differentiates
+    through the trainer."""
+    one_client = _client_update(model_loss, local_steps, batch_size, prox_mu)
+
+    def streamed(params, x, y, sizes, sel, lr, rngs, weights):
+        total = jnp.sum(weights)
+        w = weights / jnp.maximum(total, 1e-12)
+
+        def client(acc, inp):
+            k, rng, w_k = inp
+            theta = one_client(params, x[k], y[k], sizes[k], lr, rng)
+            with jax.named_scope("accumulate"):
+                acc = jax.tree_util.tree_map(
+                    lambda a, t: a + w_k * t.astype(jnp.float32), acc, theta)
+            return acc, None
+
+        acc0 = jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, jnp.float32), params)
+        acc, _ = jax.lax.scan(client, acc0, (sel, rngs, w))
+        avg = jax.tree_util.tree_map(lambda a, p: a.astype(p.dtype), acc,
+                                     params)
+        return guard_zero_weight(avg, params, total)
+
+    @custom_vmap
+    def train(*args):
+        return streamed(*args)
+
+    @train.def_vmap
+    def per_cell(size, in_batched, *args):
+        def cell(i):
+            return train(*(jax.tree_util.tree_map(
+                lambda a, b: a[i] if b else a, arg, bat)
+                for arg, bat in zip(args, in_batched)))
+        if size == 1:
+            out = jax.tree_util.tree_map(lambda a: a[None], cell(0))
+        else:
+            out = jax.lax.map(cell, jnp.arange(size))
+        return out, jax.tree_util.tree_map(lambda _: True, out)
+
+    return jax.jit(train)
 
 
 def make_loss_prober(model_loss, *, probe_size: int = 64):

@@ -1,5 +1,6 @@
 """Paper-scale federated models: logistic regression (Synthetic) and the
-McMahan-style small CNNs (vision surrogates) — pure JAX.
+McMahan-style small CNNs (vision surrogates) — pure JAX — and the model
+zoo's mixed-stack language models as client models (``language_model``).
 
 ``embed`` exposes the output-layer activations used by the functional-
 similarity 3DG construction (Eq. 12, l = output layer).
@@ -85,3 +86,20 @@ def small_cnn(shape=(8, 8, 3), classes: int = 10, width: int = 16) -> FedModel:
         accuracy=lambda p, x, y: jnp.mean(jnp.argmax(logits(p, x), 1) == y),
         embed=lambda p, x: logits(p, x),
     )
+
+
+def language_model(arch) -> FedModel:
+    """A mixed-stack LM of the zoo (``models/lm.py``, an ``ArchConfig`` with
+    ``layer_types``) as a client model.  Each row of ``x`` is one document
+    of S+1 token ids: the model reads ids 0..S-1 and predicts 1..S.  ``y``
+    is unused.  Training recomputes each layer in the backward pass."""
+    from repro.models import lm
+
+    def batch(x):
+        return {"tokens": x[:, :-1], "labels": x[:, 1:]}
+
+    return FedModel(
+        init=lambda rng: lm.init_params(rng, arch),
+        loss=lambda p, x, y: lm.train_loss(p, arch, batch(x), remat=True),
+        accuracy=lambda p, x, y: lm.next_token_accuracy(p, arch, batch(x)),
+        embed=lambda p, x: lm.mean_hidden(p, arch, x[:, :-1]))

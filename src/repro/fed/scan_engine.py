@@ -134,15 +134,16 @@ from repro.core.sampler_device import (
 from repro.core.fairness import count_variance_device, gini_device
 from repro.data.fed_dataset import FedDataset
 from repro.fed.aggregator_device import (
-    AggregatorProcess, _flat_template, init_agg_state,
-    make_aggregator_process, make_aggregator_step,
+    MEAN_FAMILIES, AggregatorProcess, _flat_template, init_agg_state,
+    init_mean_agg_state, make_aggregator_process, make_aggregator_step,
+    make_mean_aggregator_step,
 )
 from repro.fed.aggregator_device import FAMILIES as AGG_FAMILIES
 from repro.fed.faults_device import (
     FaultProcess, init_fault_state, make_fault_process, make_fault_step,
 )
 from repro.fed.faults_device import FAMILIES as FAULT_FAMILIES
-from repro.fed.client import make_local_trainer
+from repro.fed.client import make_local_trainer, make_sequential_trainer
 from repro.fed.models import FedModel
 from repro.launch.mesh import make_engine_mesh
 from repro.sharding.rules import (
@@ -158,6 +159,28 @@ FAULTS = FAULT_FAMILIES        # ("none", "sign_flip", "gaussian_noise",
 SILO_REDUCES = ("gather", "psum")
 # clients per block of the dynamic 3DG's all-clients init probe round
 INIT_BLOCK = 512
+# Local training trains client by client, streaming the Eq. 18 sum into one
+# accumulator, once the vmap trainer's (cells, M, P) stack of client models
+# would fill more than this share of the device's memory
+# (``trains_sequentially``; DESIGN.md §3)
+SEQUENTIAL_SHARE = 0.25
+# the memory taken for a device that reports none (the host CPU)
+NOMINAL_DEVICE_BYTES = 16 * 2 ** 30
+
+
+def device_memory_bytes() -> int:
+    """The memory of the engine's device, as its allocator reports it."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", NOMINAL_DEVICE_BYTES))
+
+
+def trains_sequentially(cells: int, m: int, param_bytes: int,
+                        device_bytes: int) -> bool:
+    """The size rule of local training's path: client by client when the
+    stack of every cell's M client models, ``cells x m x param_bytes``,
+    would take more than ``SEQUENTIAL_SHARE`` of ``device_bytes``; else
+    one vmap over cells x M clients."""
+    return cells * m * param_bytes > SEQUENTIAL_SHARE * device_bytes
 
 
 @dataclass(frozen=True)
@@ -341,7 +364,8 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
                     use_masks: bool, with_memory: bool = False, *,
                     with_fault: bool = False, with_stale: bool = False,
                     with_telemetry: bool = False,
-                    silo: int = 1, panel_axis: Optional[str] = None):
+                    silo: int = 1, panel_axis: Optional[str] = None,
+                    seq: Optional[tuple] = None):
     """Closure-captures the (cell-shared) dataset and returns the pure
     per-cell closures the engine jit/vmap/shard_maps:
 
@@ -379,7 +403,14 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
     shard_map "silo" mesh axis (each silo trains ceil(M/s) clients with the
     SAME per-client fold_in keys, then ``all_gather``s the stacked updates
     — bitwise equal to the unsharded program); ``panel_axis`` additionally
-    row-shards the memory panel (see ``make_aggregator_step``)."""
+    row-shards the memory panel (see ``make_aggregator_step``).
+
+    ``seq`` = (sampler families, aggregator families) of the batch's cells
+    trains client by client (``make_sequential_trainer``) with the Eq. 18
+    mean streamed into one accumulator: only those families' sampler and
+    aggregator branches are built, and the aggregator state carries only
+    what they read (``init_mean_agg_state``).  ``ScanEngine`` sets it by
+    the size rule ``trains_sequentially``."""
     n = int(ds.n_clients)
     m = int(cfg.m)
     xs = jnp.asarray(ds.x)
@@ -395,6 +426,10 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
     trainer = make_local_trainer(model.loss, local_steps=cfg.local_steps,
                                  batch_size=cfg.batch_size,
                                  prox_mu=cfg.prox_mu)
+    if seq is not None:
+        seq_trainer = make_sequential_trainer(
+            model.loss, local_steps=cfg.local_steps,
+            batch_size=cfg.batch_size, prox_mu=cfg.prox_mu)
     dynamic = cfg.graph_refresh_every > 0
     if dynamic:
         # shared Gaussian probe batch (Eq. 12), engine-level constant —
@@ -440,15 +475,19 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
     # (core/sampler_device.make_sampler_step)
     sampler_step = make_sampler_step(
         n, m, max_sweeps=cfg.max_sweeps, d_cand=d_cand,
-        probe_losses=probe_losses, solver_backend=cfg.solver_backend)
+        probe_losses=probe_losses, solver_backend=cfg.solver_backend,
+        families=None if seq is None else seq[0])
 
     # ... and the ONE aggregator step (fed/aggregator_device): the server
     # update is a per-cell lax.switch too, so mixed-aggregator cells batch;
     # the aggregator state's ``prev`` slot doubles as the param carry
-    agg_step = make_aggregator_step(
-        n, m, jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-        data_sizes=ds.sizes, backend=cfg.agg_backend,
-        memory_enabled=with_memory, panel_axis=panel_axis)
+    if seq is None:
+        agg_step = make_aggregator_step(
+            n, m, jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+            data_sizes=ds.sizes, backend=cfg.agg_backend,
+            memory_enabled=with_memory, panel_axis=panel_axis)
+    else:
+        mean_step = make_mean_aggregator_step(seq[1])
 
     # ... and the fault-injection seam (fed/faults_device) BETWEEN local
     # training and aggregation: per-cell lax.switch over the fault family,
@@ -501,8 +540,11 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
             else:
                 emb0 = jnp.zeros((1, 1), jnp.float32)
                 h0 = cell["h"]
-            astate0 = init_agg_state(params0, n, memory_rows=mem_rows,
-                                     tau_rows=n if with_memory else 0)
+            if seq is None:
+                astate0 = init_agg_state(params0, n, memory_rows=mem_rows,
+                                         tau_rows=n if with_memory else 0)
+            else:
+                astate0 = init_mean_agg_state(params0, seq[1])
             carry0 = {"agg": astate0,
                       "counts": jnp.zeros((n,), jnp.float32),
                       "h": h0, "emb": emb0,
@@ -554,7 +596,12 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
             key = jax.random.fold_in(cell["key"], t)
             key, sub = jax.random.split(key)
             keys_m = jax.random.split(sub, m)
-            if silo > 1:
+            if seq is not None:
+                # client by client: the Eq. 18 mean is streamed, no
+                # (M, P) stack of client models is made
+                avg = seq_trainer(params, xs, ys, sizes_i, sel, lr, keys_m,
+                                  sizes_f[sel] * valid)
+            elif silo > 1:
                 pad = chunk * silo - m
                 sel_p = jnp.concatenate([sel, sel[-1:].repeat(pad, 0)]) \
                     if pad else sel
@@ -599,10 +646,14 @@ def _build_simulate(ds: FedDataset, model: FedModel, cfg: ScanConfig,
         # then 5. the count update v^{t+1}
         prev_params = params
         with jax.named_scope("aggregate"):
-            agg_w = sizes_f[sel] * valid
-            params, astate = agg_step(
-                cell["agg"], astate, jax.random.fold_in(cell["agg_key"], t),
-                local, agg_w, s, avail, t, sel, valid)
+            if seq is None:
+                agg_w = sizes_f[sel] * valid
+                params, astate = agg_step(
+                    cell["agg"], astate,
+                    jax.random.fold_in(cell["agg_key"], t),
+                    local, agg_w, s, avail, t, sel, valid)
+            else:
+                params, astate = mean_step(cell["agg"], astate, avg)
             counts = counts + s.astype(jnp.float32)
 
         # dynamic 3DG: refresh participants' embeddings; rebuild every K
@@ -680,7 +731,7 @@ class ScanEngine:
         self.ds, self.model, self.cfg = ds, model, cfg
         self.n = ds.n_clients
         self.use_masks = use_masks
-        self._sims: dict = {}   # ((wm, wf, ws, wt), silo, panel) -> closures
+        self._sims: dict = {}   # ((wm, wf, ws, wt, seq), silo, panel) -> closures
         # program key -> jit'd fn: bounded LRU with hit/miss/compile-ms
         # counters (DESIGN.md §15) — the old unbounded dict leaked one
         # program per (seg_len, variant) across heterogeneous sweeps
@@ -693,6 +744,8 @@ class ScanEngine:
         self.sink = sink
         self._tel_parts: list = []    # [(t0, k, telemetry_host)] per run
         self._writer_stats: Optional[dict] = None
+        self._param_bytes: Optional[int] = None
+        self._seq_programs = 0        # programs built on the sequential path
 
     def runtime_stats(self) -> dict:
         """The unified telemetry snapshot (DESIGN.md §17): program-cache
@@ -704,10 +757,24 @@ class ScanEngine:
         folds = [sim["fold_stats"] for sim in self._sims.values()]
         fold = {"folds": sum(f["folds"] for f in folds),
                 "clients": max((f["clients"] for f in folds), default=0)}
+        on = self._seq_programs > 0
+        seq = {"programs": self._seq_programs,
+               "clients": int(self.cfg.m) if on else 0,
+               "param_bytes": self.param_bytes() if on else 0}
         return runtime_snapshot(programs=self._programs,
                                 writer=self._writer_stats,
                                 tracer=self.tracer,
-                                extra={"local_train_fold": fold})
+                                extra={"local_train_fold": fold,
+                                       "local_train_seq": seq})
+
+    def param_bytes(self) -> int:
+        """Bytes of one copy of the model's parameters."""
+        if self._param_bytes is None:
+            shapes = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
+            self._param_bytes = int(sum(
+                np.prod(a.shape) * np.dtype(a.dtype).itemsize
+                for a in jax.tree_util.tree_leaves(shapes)))
+        return self._param_bytes
 
     def attach_sink(self, sink):
         """Install (or clear, with ``None``) the streaming metrics sink —
@@ -724,11 +791,13 @@ class ScanEngine:
 
     def _flags(self, cells: list[dict]) -> tuple:
         """Static program-variant flags for this batch: ``(wm, wf, ws,
-        wt)`` — does any cell need the (N, P) update-memory panel / the
-        fault seam / the straggler stale panel, and is the in-scan
+        wt, seq)`` — does any cell need the (N, P) update-memory panel /
+        the fault seam / the straggler stale panel, and is the in-scan
         telemetry channel on?  Each flag widens the carry or the traced
         step only for batches that actually use the feature, so the
-        benign default program is unchanged."""
+        benign default program is unchanged.  ``seq`` is None, or where
+        the size rule (``trains_sequentially``) has local training go
+        client by client, the batch's (sampler, aggregator) families."""
         midx = AGGREGATORS.index("memory")
         wm = self.cfg.aggregator == "memory" or any(
             int(np.asarray(c["agg"]["family"])) == midx for c in cells)
@@ -739,7 +808,39 @@ class ScanEngine:
         wf = self.cfg.fault != "none" or any(f != nidx for f in fams)
         ws = self.cfg.fault == "straggler_stale" or any(
             f == sidx for f in fams)
-        return wm, wf, ws, bool(self.cfg.telemetry)
+        wt = bool(self.cfg.telemetry)
+        seq = None
+        if trains_sequentially(len(cells), int(self.cfg.m),
+                               self.param_bytes(), device_memory_bytes()):
+            seq = self._sequential_families(cells, wf, wt)
+        return wm, wf, ws, wt, seq
+
+    def _sequential_families(self, cells: list[dict], wf: bool,
+                             wt: bool) -> tuple:
+        """The (sampler, aggregator) families of a batch that trains client
+        by client; raises where the batch needs what that path never
+        makes: the (M, P) stack of client models."""
+        def fams(names, sub):
+            used = {int(np.asarray(c[sub]["family"])) for c in cells}
+            return tuple(f for i, f in enumerate(names) if i in used)
+        samplers, aggs = fams(SAMPLERS, "sampler"), fams(AGGREGATORS, "agg")
+        gb = len(cells) * int(self.cfg.m) * self.param_bytes() / 1e9
+        needs = [f"the {a!r} aggregator" for a in aggs
+                 if a not in MEAN_FAMILIES]
+        needs += ["fault injection"] * wf + ["in-scan telemetry"] * wt
+        if self.cfg.graph_refresh_every > 0:
+            needs.append("the dynamic 3DG")
+        if self.cfg.mesh is not None and self.cfg.mesh[1] > 1:
+            needs.append("the silo mesh axis")
+        if needs:
+            raise ValueError(
+                f"local training runs client by client here: the stack of "
+                f"{len(cells)} cells x m={self.cfg.m} client models "
+                f"({gb:.2f} GB) exceeds {SEQUENTIAL_SHARE:.0%} of the "
+                f"device's {device_memory_bytes() / 1e9:.2f} GB, and that "
+                f"path makes no (M, P) stack of client models, which "
+                f"{', '.join(needs)} needs")
+        return samplers, aggs
 
     def _variant(self, batched: bool):
         """(mesh, silo, panel_axis-factory) for this run shape."""
@@ -752,13 +853,13 @@ class ScanEngine:
         return mesh, silo, panel
 
     def _closures(self, flags: tuple, silo: int, panel: Optional[str]):
-        wm, wf, ws, wt = flags
+        wm, wf, ws, wt, seq = flags
         key = (flags, silo, panel)
         if key not in self._sims:
             self._sims[key] = _build_simulate(
                 self.ds, self.model, self.cfg, self.use_masks,
                 with_memory=wm, with_fault=wf, with_stale=ws,
-                with_telemetry=wt, silo=silo, panel_axis=panel)
+                with_telemetry=wt, silo=silo, panel_axis=panel, seq=seq)
         return self._sims[key]
 
     def _program(self, cells: list[dict], batched: bool):
@@ -773,6 +874,7 @@ class ScanEngine:
         panel = panelf(flags[0])
 
         def build():
+            self._seq_programs += flags[4] is not None
             fn = self._closures(flags, silo, panel)["simulate"]
             if batched:
                 fn = jax.vmap(fn)
@@ -818,6 +920,7 @@ class ScanEngine:
         donate = bool(self.cfg.donate_carry)
 
         def build():
+            self._seq_programs += flags[4] is not None
             cl = self._closures(flags, silo, panel)
             fn = jax.vmap(cl["segment"](seg_len), in_axes=(0, 0, None))
             if mesh is not None:

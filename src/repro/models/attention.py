@@ -43,11 +43,12 @@ def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
 
 
 def attend_dense(q, k, v, *, causal: bool, window: int | None,
-                 q_offset: int = 0) -> jax.Array:
-    """Materialized attention. q (B,Sq,H,D), k/v (B,Sk,H,D) (kv already repeated)."""
+                 q_offset: int = 0, scale: float | None = None) -> jax.Array:
+    """Materialized attention. q (B,Sq,H,D), k/v (B,Sk,H,D) (kv already repeated).
+    ``scale`` None: 1/sqrt(D)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    scale = 1.0 / np.sqrt(d)
+    scale = 1.0 / np.sqrt(d) if scale is None else scale
     sdt = jnp.dtype(SCORE_DTYPE)
     neg = jnp.asarray(-6e4 if sdt == jnp.bfloat16 else NEG_INF, sdt)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(sdt) * jnp.asarray(scale, sdt)
@@ -66,7 +67,8 @@ def attend_dense(q, k, v, *, causal: bool, window: int | None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def attend_chunked_full(q, k, v, *, causal: bool = True) -> jax.Array:
+def attend_chunked_full(q, k, v, *, causal: bool = True,
+                        scale: float | None = None) -> jax.Array:
     """Online-softmax over KV chunks (flash pattern).  All-queries-at-once.
 
     Memory: O(B·H·Sq·KV_CHUNK) transient instead of O(Sq·Sk).
@@ -75,7 +77,7 @@ def attend_chunked_full(q, k, v, *, causal: bool = True) -> jax.Array:
     sk = k.shape[1]
     assert sk % KV_CHUNK == 0, (sk, KV_CHUNK)
     n_kv = sk // KV_CHUNK
-    scale = 1.0 / np.sqrt(d)
+    scale = 1.0 / np.sqrt(d) if scale is None else scale
     qpos = jnp.arange(sq)
 
     kc = k.reshape(b, n_kv, KV_CHUNK, h, d)
@@ -109,7 +111,8 @@ def attend_chunked_full(q, k, v, *, causal: bool = True) -> jax.Array:
     return out.astype(q.dtype)
 
 
-def attend_windowed(q, k, v, *, window: int) -> jax.Array:
+def attend_windowed(q, k, v, *, window: int,
+                    scale: float | None = None) -> jax.Array:
     """Causal sliding-window attention via Q-chunk scan over static KV spans.
 
     Query chunk i (length C) attends to kv span of static length W+C ending at
@@ -137,7 +140,8 @@ def attend_windowed(q, k, v, *, window: int) -> jax.Array:
         vb = jax.lax.dynamic_slice_in_dim(vp, start, span, axis=1)
         qpos = i * c + jnp.arange(c)
         kpos = start - pad + jnp.arange(span)   # true positions (can be negative)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb).astype(jnp.float32) / np.sqrt(d)
+        s = jnp.einsum("bqhd,bkhd->bhqk", qb, kb).astype(jnp.float32)
+        s = s / np.sqrt(d) if scale is None else s * scale
         msk = (kpos[None, :] <= qpos[:, None]) & (kpos[None, :] > qpos[:, None] - window) & (kpos[None, :] >= 0)
         s = jnp.where(msk[None, None], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
@@ -147,14 +151,16 @@ def attend_windowed(q, k, v, *, window: int) -> jax.Array:
     return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, d)
 
 
-def multihead_attention(q, k, v, *, causal: bool, window: int | None) -> jax.Array:
-    """Dispatch on sequence length / window. kv heads already repeated to q heads."""
+def multihead_attention(q, k, v, *, causal: bool, window: int | None,
+                        scale: float | None = None) -> jax.Array:
+    """Dispatch on sequence length / window. kv heads already repeated to q
+    heads.  ``scale`` (None: 1/sqrt(D)) is the score multiplier."""
     sq, sk = q.shape[1], k.shape[1]
     if window is not None and sk > window + Q_CHUNK and sq == sk:
-        return attend_windowed(q, k, v, window=window)
+        return attend_windowed(q, k, v, window=window, scale=scale)
     if max(sq, sk) <= DENSE_MAX:
-        return attend_dense(q, k, v, causal=causal, window=window)
-    return attend_chunked_full(q, k, v, causal=causal)
+        return attend_dense(q, k, v, causal=causal, window=window, scale=scale)
+    return attend_chunked_full(q, k, v, causal=causal, scale=scale)
 
 
 def decode_attend_ring(q, k_ring, v_ring, cache_len, *, window: int) -> jax.Array:
@@ -176,7 +182,8 @@ def decode_attend_ring(q, k_ring, v_ring, cache_len, *, window: int) -> jax.Arra
     return jnp.einsum("bhqk,bkhd->bqhd", p, v_ring)
 
 
-def decode_attend(q, k_cache, v_cache, cache_len, *, window: int | None) -> jax.Array:
+def decode_attend(q, k_cache, v_cache, cache_len, *, window: int | None,
+                  scale: float | None = None) -> jax.Array:
     """One-token decode. q (B,1,H,D); caches (B,Smax,Hkv_rep,D); cache_len scalar.
 
     For windowed attention only the last ``window`` entries are read
@@ -193,7 +200,10 @@ def decode_attend(q, k_cache, v_cache, cache_len, *, window: int | None) -> jax.
     else:
         kb, vb = k_cache, v_cache
         kpos = jnp.arange(smax)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, kb).astype(jnp.float32) / np.sqrt(d)
+    if scale is None:
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kb).astype(jnp.float32) / np.sqrt(d)
+    else:
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kb).astype(jnp.float32) * scale
     msk = kpos < cache_len
     s = jnp.where(msk[None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)

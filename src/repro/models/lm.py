@@ -6,14 +6,21 @@ covering all six assigned families:
   dense   — GQA attention + (SwiGLU | squared-ReLU) FFN
   moe     — GQA attention + token-choice top-k MoE FFN
   ssm     — Mamba-2/SSD mixer, no FFN
-  hybrid  — parallel attention + SSD heads, then FFN (Hymba)
+  hybrid  — parallel attention + SSD heads, then FFN (Hymba); or, with
+            ``cfg.layer_types``, a mixed stack of Mamba-2 and attention
+            layers, each mixer followed by the FFN (granite-4.0-h)
   vlm     — dense/GQA decoder consuming [image-embeddings ; token-embeddings]
   audio   — enc-dec: bidirectional encoder over frame embeddings, causal
             decoder with cross-attention
 
 Parameters are dict pytrees with per-layer leaves **stacked on a leading L
 axis**; the forward is ``lax.scan`` over layers (+ ``jax.checkpoint`` remat in
-training) so 96-layer models lower as fast as 1-layer models.
+training) so 96-layer models lower as fast as 1-layer models.  A mixed stack
+(``cfg.layer_types``) instead keeps one param dict per layer under
+``params["layers"]`` and runs the layers in order, each recomputed in the
+backward pass in training (full per-layer recompute: only the layer inputs
+are saved).  Its ops are named by layer part: ``mamba``,
+``attention``, ``mlp`` and ``lm_head`` (embedding, final norm, logits, loss).
 
 Three entry points (used by launch/, fed/, tests/):
   train_loss(params, cfg, batch)                -> scalar loss
@@ -75,8 +82,40 @@ def _init_block(key, cfg: ArchConfig, dtype, *, cross: bool = False):
     return p
 
 
+def _init_layer(key, cfg: ArchConfig, kind: str, dtype):
+    """One layer of a mixed stack: its mixer under ``kind`` ("mamba" or
+    "attention", each with its pre-norm), then the FFN under "mlp"."""
+    km, kf = jax.random.split(key)
+    d = cfg.d_model
+    if kind == "attention":
+        mixer = _init_attn(km, cfg, dtype)
+    else:
+        mixer = {"norm": jnp.ones((d,), jnp.float32),
+                 **ssd_mod.init_ssd(km, d, cfg.ssm, dtype)}
+    return {kind: mixer,
+            "mlp": {"norm": jnp.ones((d,), jnp.float32),
+                    **ffn_mod.init_ffn(kf, d, cfg.d_ff, cfg.ffn_kind, dtype)}}
+
+
+def _init_mixed(key, cfg: ArchConfig, dtype):
+    """Key i+1 of ``split(key, L + 1)`` makes layer i; key 0 the embedding
+    (and, untied, ``fold_in(key 0, 1)`` the head)."""
+    ks = jax.random.split(key, len(cfg.layer_types) + 1)
+    v, d = cfg.padded_vocab, cfg.d_model
+    params = {"embed": embed_init(ks[0], v, d, dtype),
+              "layers": [_init_layer(k, cfg, kind, dtype)
+                         for k, kind in zip(ks[1:], cfg.layer_types)],
+              "final_norm": jnp.ones((d,), jnp.float32)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense_init(jax.random.fold_in(ks[0], 1), d, v,
+                                       dtype)
+    return params
+
+
 def init_params(key, cfg: ArchConfig):
     dtype = jnp.dtype(cfg.dtype)
+    if cfg.layer_types is not None:
+        return _init_mixed(key, cfg, dtype)
     ks = jax.random.split(key, 5)
     v, d = cfg.padded_vocab, cfg.d_model
 
@@ -151,14 +190,16 @@ def _attn_fwd(p, x, cfg: ArchConfig, *, causal, window, positions,
     if kv_override is None:
         k = (h @ p["wk"]).reshape(b, s, hkv, dh)
         v = (h @ p["wv"]).reshape(b, s, hkv, dh)
-        cos, sin = rope_angles(positions, dh, cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cfg.position_embedding == "rope":
+            cos, sin = rope_angles(positions, dh, cfg.rope_theta)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
     else:
         k, v = kv_override
     k = attn_mod._repeat_kv(k, hq // hkv)
     v = attn_mod._repeat_kv(v, hq // hkv)
-    o = attn_mod.multihead_attention(q, k, v, causal=causal, window=window)
+    o = attn_mod.multihead_attention(q, k, v, causal=causal, window=window,
+                                     scale=cfg.attn_scale)
     o = o.reshape(b, s, hq * dh) @ p["wo"]
     return shard_act(o, "dp", None, None)
 
@@ -269,6 +310,8 @@ def _embed_inputs(params, cfg: ArchConfig, batch):
     """Token (+ multimodal stub) embedding. Returns (x, positions, loss_mask)."""
     tokens = batch["tokens"]
     x = params["embed"][tokens]            # gather
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     prefix = []
     if cfg.family == "vlm" and "image_emb" in batch:
         prefix.append(batch["image_emb"].astype(x.dtype))
@@ -282,6 +325,8 @@ def _lm_logits(params, cfg: ArchConfig, x):
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ head
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return shard_act(logits, "dp", None, "tp")
 
 
@@ -305,6 +350,8 @@ def _chunked_cross_entropy(params, cfg: ArchConfig, x, labels, mask):
     def body(carry, inp):
         xs, ls, ms = inp
         logits = (xs @ head).astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         logits = shard_act(logits, "dp", None, "tp")
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, ls[..., None], axis=-1)[..., 0]
@@ -326,10 +373,160 @@ def _encode(params, cfg: ArchConfig, frames):
     return rms_norm(x, params["enc_norm"], cfg.norm_eps)
 
 
+# ============================================================ mixed stacks
+def _mixer(p, x, cfg: ArchConfig, kind: str, positions):
+    """The mixer of one mixed-stack layer over its pre-norm of ``x``.
+    Returns (out, what a decode cache keeps: (k, v) or (ssm, conv))."""
+    if kind == "attention":
+        pa = p["attention"]
+        b, s, _ = x.shape
+        hn = rms_norm(x, pa["norm"], cfg.norm_eps)
+        k = (hn @ pa["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        v = (hn @ pa["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        if cfg.position_embedding == "rope":
+            cos, sin = rope_angles(positions, cfg.head_dim, cfg.rope_theta)
+            k = apply_rope(k, cos, sin)
+        window = cfg.window if cfg.attention == "sliding_window" else None
+        out = _attn_fwd(pa, x, cfg, causal=True, window=window,
+                        positions=positions)
+        return out, (k, v)
+    sp = {k: v for k, v in p["mamba"].items() if k != "norm"}
+    return ssd_mod.apply_ssd(
+        sp, rms_norm(x, p["mamba"]["norm"], cfg.norm_eps), cfg.ssm)
+
+
+def _mixed_layer(p, x, cfg: ArchConfig, kind: str, positions):
+    """x + r * mixer(norm(x)), then x + r * mlp(norm(x)) (r the residual
+    multiplier).  Returns (x, the mixer's decode-cache entries)."""
+    r = cfg.residual_multiplier
+    with jax.named_scope(kind):
+        out, entries = _mixer(p, x, cfg, kind, positions)
+        x = x + out * r
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["mlp"]["norm"], cfg.norm_eps)
+        fp = {k: v for k, v in p["mlp"].items() if k != "norm"}
+        x = x + ffn_mod.apply_ffn(fp, h, cfg.ffn_kind) * r
+    return x, entries
+
+
+def mixed_hidden(params, cfg: ArchConfig, tokens, *, remat=False):
+    """The mixed stack's last hidden state (before the final norm), each
+    layer recomputed in the backward pass when ``remat`` (full per-layer
+    recompute: the default ``dots`` policy of ``_remat`` would keep every
+    matmul output, ~9 GB for ten layers at 8,192 tokens)."""
+    with jax.named_scope("lm_head"):
+        x, positions = _embed_inputs(params, cfg, {"tokens": tokens})
+    for kind, p in zip(cfg.layer_types, params["layers"]):
+        def layer(p, x, kind=kind):
+            return _mixed_layer(p, x, cfg, kind, positions)[0]
+        x = (jax.checkpoint(layer) if remat else layer)(p, x)
+    return x
+
+
+def _mixed_prefill(params, cfg: ArchConfig, batch):
+    x, positions = _embed_inputs(params, cfg, batch)
+    caches: dict = {"k": [], "v": [], "ssm": [], "conv": []}
+    for kind, p in zip(cfg.layer_types, params["layers"]):
+        x, entries = _mixed_layer(p, x, cfg, kind, positions)
+        names = ("k", "v") if kind == "attention" else ("ssm", "conv")
+        for name, e in zip(names, entries):
+            caches[name].append(e)
+    cache = {k: jnp.stack(v) for k, v in caches.items() if v}
+    cache["len"] = jnp.asarray(x.shape[1], jnp.int32)
+    return _lm_logits(params, cfg, x[:, -1:])[:, 0], cache
+
+
+def _mixed_decode(params, cfg: ArchConfig, tokens, cache):
+    b = tokens.shape[0]
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    x, _ = _embed_inputs(params, cfg, {"tokens": tokens[:, None]})
+    pos = cache["len"][None, None] + jnp.zeros((b, 1), jnp.int32)
+    window = cfg.window if cfg.attention == "sliding_window" else None
+    r = cfg.residual_multiplier
+    new: dict = {"k": [], "v": [], "ssm": [], "conv": []}
+    for kind, p in zip(cfg.layer_types, params["layers"]):
+        if kind == "attention":
+            pa, i = p["attention"], len(new["k"])
+            hn = rms_norm(x, pa["norm"], cfg.norm_eps)
+            q = (hn @ pa["wq"]).reshape(b, 1, hq, dh)
+            k = (hn @ pa["wk"]).reshape(b, 1, hkv, dh)
+            v = (hn @ pa["wv"]).reshape(b, 1, hkv, dh)
+            if cfg.position_embedding == "rope":
+                cos, sin = rope_angles(pos, dh, cfg.rope_theta)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+            kc = jax.lax.dynamic_update_slice_in_dim(cache["k"][i], k,
+                                                     cache["len"], axis=1)
+            vc = jax.lax.dynamic_update_slice_in_dim(cache["v"][i], v,
+                                                     cache["len"], axis=1)
+            new["k"].append(kc)
+            new["v"].append(vc)
+            o = attn_mod.decode_attend(
+                q, attn_mod._repeat_kv(kc, hq // hkv),
+                attn_mod._repeat_kv(vc, hq // hkv), cache["len"] + 1,
+                window=window, scale=cfg.attn_scale)
+            out = o.reshape(b, 1, hq * dh) @ pa["wo"]
+        else:
+            i = len(new["ssm"])
+            sp = {kk: vv for kk, vv in p["mamba"].items() if kk != "norm"}
+            out, (st, cv) = ssd_mod.ssd_decode_step(
+                sp, rms_norm(x, p["mamba"]["norm"], cfg.norm_eps), cfg.ssm,
+                cache["ssm"][i], cache["conv"][i])
+            new["ssm"].append(st)
+            new["conv"].append(cv)
+        x = x + out * r
+        hn = rms_norm(x, p["mlp"]["norm"], cfg.norm_eps)
+        fp = {kk: vv for kk, vv in p["mlp"].items() if kk != "norm"}
+        x = x + ffn_mod.apply_ffn(fp, hn, cfg.ffn_kind) * r
+    new_cache = {k: jnp.stack(v) for k, v in new.items() if v}
+    new_cache["len"] = cache["len"] + 1
+    return _lm_logits(params, cfg, x)[:, 0], new_cache
+
+
+def next_token_accuracy(params, cfg: ArchConfig, batch):
+    """Share of positions whose largest logit is the label, over the
+    positions the loss counts; the logits are made one chunk at a time, as
+    in ``_chunked_cross_entropy``."""
+    x = mixed_hidden(params, cfg, batch["tokens"])
+    labels = batch["labels"]
+    mask = ((labels >= 0) & (labels < cfg.vocab_size)).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        b, s, d = x.shape
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        chunk = LOSS_CHUNK if s % LOSS_CHUNK == 0 else s
+        nc = s // chunk
+
+        def body(carry, inp):
+            xs, ls, ms = inp
+            hit = jnp.argmax(xs @ head, axis=-1) == ls
+            return carry + jnp.sum(hit * ms), None
+
+        tot, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (
+            x.reshape(b, nc, chunk, d).transpose(1, 0, 2, 3),
+            labels.reshape(b, nc, chunk).transpose(1, 0, 2),
+            mask.reshape(b, nc, chunk).transpose(1, 0, 2)))
+        return tot / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def mean_hidden(params, cfg: ArchConfig, tokens):
+    """(B, d): the final-normed last hidden state, averaged over positions."""
+    x = mixed_hidden(params, cfg, tokens)
+    with jax.named_scope("lm_head"):
+        return jnp.mean(rms_norm(x, params["final_norm"], cfg.norm_eps), 1)
+
+
 # ==================================================================== train
 def train_loss(params, cfg: ArchConfig, batch, *, remat=True, aux_weight=0.01):
     """Next-token LM loss.  batch: tokens (B,S), labels (B,S), optional
     image_emb (B,Ni,d) / audio_frames (B,Nf,d)."""
+    if cfg.layer_types is not None:
+        x = mixed_hidden(params, cfg, batch["tokens"], remat=remat)
+        labels = batch["labels"]
+        mask = (labels >= 0) & (labels < cfg.vocab_size)
+        with jax.named_scope("lm_head"):
+            return _chunked_cross_entropy(params, cfg, x,
+                                          jnp.maximum(labels, 0), mask)
     enc_kv = None
     if cfg.enc_dec:
         enc_mem = _encode(params, cfg, batch["audio_frames"])
@@ -355,18 +552,21 @@ def init_decode_cache(cfg: ArchConfig, batch: int, max_len: int,
                       enc_len: int = 0, dtype=None):
     """Abstract-shape-compatible cache pytree (all zeros)."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    L = cfg.n_layers
+    L = La = Ls = cfg.n_layers
+    if cfg.layer_types is not None:      # a mixed stack caches by kind
+        La = cfg.layer_types.count("attention")
+        Ls = cfg.layer_types.count("mamba")
     cache: dict[str, Any] = {"len": jnp.zeros((), jnp.int32)}
-    if cfg.attention != "none":
+    if cfg.attention != "none" and La:
         hkv, dh = cfg.n_kv_heads, cfg.head_dim
-        cache["k"] = jnp.zeros((L, batch, max_len, hkv, dh), dtype)
-        cache["v"] = jnp.zeros((L, batch, max_len, hkv, dh), dtype)
-    if cfg.ssm is not None:
+        cache["k"] = jnp.zeros((La, batch, max_len, hkv, dh), dtype)
+        cache["v"] = jnp.zeros((La, batch, max_len, hkv, dh), dtype)
+    if cfg.ssm is not None and Ls:
         s = cfg.ssm
         d_in = s.expand * cfg.d_model
         nh = d_in // s.head_dim
-        cache["ssm"] = jnp.zeros((L, batch, nh, s.head_dim, s.d_state), jnp.float32)
-        cache["conv"] = jnp.zeros((L, batch, s.d_conv - 1, d_in + 2 * s.d_state), dtype)
+        cache["ssm"] = jnp.zeros((Ls, batch, nh, s.head_dim, s.d_state), jnp.float32)
+        cache["conv"] = jnp.zeros((Ls, batch, s.d_conv - 1, d_in + 2 * s.d_state), dtype)
     if cfg.enc_dec:
         hkv, dh = cfg.n_kv_heads, cfg.head_dim
         cache["enc_k"] = jnp.zeros((L, batch, enc_len, hkv, dh), dtype)
@@ -376,6 +576,8 @@ def init_decode_cache(cfg: ArchConfig, batch: int, max_len: int,
 
 def prefill(params, cfg: ArchConfig, batch):
     """Forward over a prompt; returns (last-position logits, populated cache)."""
+    if cfg.layer_types is not None:
+        return _mixed_prefill(params, cfg, batch)
     enc_kv = None
     enc_mem = None
     if cfg.enc_dec:
@@ -428,8 +630,12 @@ def decode_step(params, cfg: ArchConfig, tokens, cache, *, audio_frames=None):
 
     Returns (logits (B, V), new cache).
     """
+    if cfg.layer_types is not None:
+        return _mixed_decode(params, cfg, tokens, cache)
     b = tokens.shape[0]
     x = params["embed"][tokens][:, None]                  # (B,1,d)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     pos = cache["len"][None, None] + jnp.zeros((b, 1), jnp.int32)
     window = cfg.window if cfg.attention == "sliding_window" else None
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim

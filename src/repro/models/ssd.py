@@ -9,6 +9,11 @@ Chunked form: within-chunk attention-like term + cross-chunk state recurrence
 Params are separate projections (w_z/w_x/w_B/w_C/w_dt) instead of the fused
 in_proj so tensor-parallel sharding can target head-aligned dims — see
 DESIGN.md hardware-adaptation notes.
+
+``SSMConfig.conv_bias`` adds the causal conv's bias (``conv_b``) and
+``SSMConfig.gated_norm`` the published mixer's gated RMSNorm before
+``out_proj`` (``gate_norm``): ``rms_norm(y * silu(z)) * w`` in place of the
+plain gate ``y * silu(z)``.
 """
 from __future__ import annotations
 
@@ -16,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, rms_norm
 
 
 def init_ssd(key, d_model: int, cfg, dtype):
@@ -27,7 +32,12 @@ def init_ssd(key, d_model: int, cfg, dtype):
     dt_init = jnp.log(jnp.expm1(jnp.exp(
         jax.random.uniform(ks[5], (nheads,), jnp.float32,
                            np.log(1e-3), np.log(1e-1)))))
-    return {
+    extra = {}
+    if cfg.conv_bias:
+        extra["conv_b"] = jnp.zeros((d_in + 2 * cfg.d_state,), dtype)
+    if cfg.gated_norm:
+        extra["gate_norm"] = jnp.ones((d_in,), jnp.float32)
+    return {**extra,
         "w_z": dense_init(ks[0], d_model, d_in, dtype),
         "w_x": dense_init(ks[1], d_model, d_in, dtype),
         "w_B": dense_init(ks[2], d_model, cfg.d_state, dtype),
@@ -42,14 +52,27 @@ def init_ssd(key, d_model: int, cfg, dtype):
     }
 
 
-def _causal_conv(u: jax.Array, w: jax.Array, init_state: jax.Array | None = None):
-    """Depthwise causal conv. u (B,S,C), w (K,C). Returns (y, last K-1 inputs)."""
+def _causal_conv(u: jax.Array, w: jax.Array, init_state: jax.Array | None = None,
+                 bias: jax.Array | None = None):
+    """Depthwise causal conv (+ bias), SiLU. u (B,S,C), w (K,C).
+    Returns (y, last K-1 inputs)."""
     k = w.shape[0]
     if init_state is None:
         init_state = jnp.zeros((u.shape[0], k - 1, u.shape[2]), u.dtype)
     up = jnp.concatenate([init_state, u], axis=1)
     y = sum(up[:, i:i + u.shape[1]] * w[i][None, None] for i in range(k))
+    if bias is not None:
+        y = y + bias
     return jax.nn.silu(y), up[:, -(k - 1):]
+
+
+def _gate(params, y, z):
+    """The output gate: the plain ``y * silu(z)``, or with ``gate_norm``
+    the published gated RMSNorm ``rms_norm(y * silu(z)) * w``."""
+    y = y * jax.nn.silu(z)
+    if "gate_norm" in params:
+        y = rms_norm(y, params["gate_norm"])
+    return y
 
 
 def _segsum(a: jax.Array) -> jax.Array:
@@ -137,7 +160,8 @@ def apply_ssd(params, x, cfg, *, state=None, conv_state=None):
     z = x @ params["w_z"]                               # gate
     xbc = jnp.concatenate(
         [x @ params["w_x"], x @ params["w_B"], x @ params["w_C"]], axis=-1)
-    xbc, new_conv = _causal_conv(xbc, params["conv_w"], conv_state)
+    xbc, new_conv = _causal_conv(xbc, params["conv_w"], conv_state,
+                                 params.get("conv_b"))
     xs, B, C = jnp.split(xbc, [d_in, d_in + n], axis=-1)
 
     dt = jax.nn.softplus((x @ params["w_dt"]).astype(jnp.float32)
@@ -162,8 +186,7 @@ def apply_ssd(params, x, cfg, *, state=None, conv_state=None):
     y, new_state = ssd_chunked(xh, dt, A, B, C, params["D"],
                                chunk=chunk, init_state=state)
     y = y.reshape(xs_p.shape[0], xs_p.shape[1], d_in)[:, :s_len]
-    y = y * jax.nn.silu(z)
-    return y @ params["w_out"], (new_state, new_conv)
+    return _gate(params, y, z) @ params["w_out"], (new_state, new_conv)
 
 
 def ssd_decode_step(params, x, cfg, state, conv_state):
@@ -176,7 +199,8 @@ def ssd_decode_step(params, x, cfg, state, conv_state):
     z = x @ params["w_z"]
     xbc = jnp.concatenate(
         [x @ params["w_x"], x @ params["w_B"], x @ params["w_C"]], axis=-1)
-    xbc, new_conv = _causal_conv(xbc, params["conv_w"], conv_state)
+    xbc, new_conv = _causal_conv(xbc, params["conv_w"], conv_state,
+                                 params.get("conv_b"))
     xs, B, C = jnp.split(xbc, [d_in, d_in + n], axis=-1)
 
     dt = jax.nn.softplus((x @ params["w_dt"]).astype(jnp.float32)
@@ -191,5 +215,4 @@ def ssd_decode_step(params, x, cfg, state, conv_state):
     new_state = state * decay[..., None, None] + upd
     y = jnp.einsum("bhpn,bn->bhp", new_state, Ct) + xh * params["D"][None, :, None]
     y = y.reshape(x.shape[0], 1, d_in).astype(x.dtype)
-    y = y * jax.nn.silu(z)
-    return y @ params["w_out"], (new_state, new_conv)
+    return _gate(params, y, z) @ params["w_out"], (new_state, new_conv)

@@ -151,3 +151,42 @@ def test_folded_local_training_bytes_for_v5e(one_chip):
         return (cost[0] if isinstance(cost, list) else cost)["bytes accessed"]
     assert bytes_accessed(make_local_trainer) <= \
         0.6 * bytes_accessed(nested_trainer)
+
+
+def test_xsilo_granite_segment_fits_v5e(one_chip):
+    """The cross-silo hybrid LM cell's segment program (granite-4.0-h-micro,
+    one period of 10 layers, 798M float32 parameters, two silos a round
+    trained client by client) compiles for the chip, and the peak of the
+    buffers the compiler assigns it (arguments, temporaries and code) is
+    under 15.5 GB of the v5e's 16 GiB."""
+    import numpy as np
+
+    from chipbench import run
+    from chipbench.data import make_dataset
+    from chipbench.generator import (
+        cell_specs, engine_cells, engine_config, fed_dataset, fed_model,
+        mode_tables,
+    )
+    from repro.fed.scan_engine import ScanEngine, stack_cells
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = run.load_cell(root, "xsilo-granite-h-micro")
+    cfg, traffic = cell["cfg"], cell["traffic"]
+    ds = make_dataset(cfg)
+    tables = mode_tables(traffic, ds)
+    eng = ScanEngine(fed_dataset(ds), fed_model(root, cfg, ds),
+                     engine_config(cfg, traffic), use_masks=True)
+    n = ds.n_clients
+    cells = engine_cells(eng, traffic, cell_specs(
+        cfg, traffic, ds, 4100000000, 0, tables), np.zeros((n, n)))
+    flags = eng._flags(cells)
+    assert flags[4] == (("fedgs",), ("fedavg",))    # client by client
+    stacked = stack_cells(cells)
+
+    def s(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    carry = jax.eval_shape(eng._init_program(stacked, flags), stacked)
+    compiled = eng._segment_program(
+        stacked, flags, traffic["segment_rounds"]).lower(
+        jax.tree_util.tree_map(s, stacked), jax.tree_util.tree_map(s, carry),
+        s(jnp.int32(0))).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15.5e9
